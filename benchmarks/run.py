@@ -19,6 +19,7 @@ from . import (bench_batch_scaling, bench_complex_filter, bench_e2e,
                bench_simple_filter, bench_storage, bench_transform,
                bench_traversal)
 from .util import header, set_suite, write_json
+from repro.launch.compile_cache import use_compile_cache
 
 SUITES = {
     "fig8_storage": bench_storage.run,
@@ -46,6 +47,7 @@ SUITES = {
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated suite names")

@@ -15,6 +15,7 @@ from repro.core.query import (bi2_acero, bi2_graphar, build_snb_baseline,
                               is3_acero, is3_graphar)
 from repro.core.storage import ESSD
 from repro.data.synthetic import ldbc_like
+from repro.launch.compile_cache import use_compile_cache
 
 
 def timed(fn):
@@ -24,6 +25,7 @@ def timed(fn):
 
 
 def main():
+    use_compile_cache()
     print("generating LDBC-like graph (scale 2)...")
     snb = ldbc_like(scale=2, seed=0)
     g = build_snb_graphar(snb)

@@ -13,9 +13,11 @@ from repro.core import (BY_SRC, EdgeTypeSchema, GraphArBuilder, GraphStore,
                         neighbor_properties, retrieve_neighbors)
 from repro.core.storage import ESSD
 from repro.data.synthetic import clustered_labels, powerlaw_graph
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     # -- 1. raw data: a small social graph with labeled persons ------------
     n = 20_000
     src, dst = powerlaw_graph(n, avg_degree=10, seed=0)

@@ -25,6 +25,7 @@ from repro.core import (BY_SRC, EdgeTypeSchema, GraphArBuilder, IOMeter, L,
                         PropertySchema, VertexTypeSchema)
 from repro.configs import get_config
 from repro.data.synthetic import document_graph
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.retrieval import GraphRetriever
@@ -32,6 +33,7 @@ from repro.serve.tenancy import TenantConfig
 
 
 def main():
+    use_compile_cache()
     # -- lake with passage tokens (retrieval source) -------------------------
     lake = document_graph(num_docs=1000, vocab=512, mean_len=48, seed=2)
     b = GraphArBuilder("passages")

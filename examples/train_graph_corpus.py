@@ -21,6 +21,7 @@ from repro.core import (EdgeTypeSchema, GraphArBuilder, L, PropertySchema,
                         VertexTypeSchema)
 from repro.data.pipeline import GraphCorpusPipeline, PipelineConfig
 from repro.data.synthetic import document_graph
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model, param_count
 from repro.train.optimizer import adamw
 from repro.train.schedule import warmup_cosine
@@ -28,6 +29,7 @@ from repro.train.trainer import Trainer, TrainerConfig
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--seq_len", type=int, default=256)

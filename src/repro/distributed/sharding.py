@@ -22,7 +22,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 
@@ -214,20 +214,10 @@ def replicated(mesh: Mesh):
 # in-model activation constraints (mesh-context-aware, no-op without a mesh)
 # ---------------------------------------------------------------------------
 
-def _context_mesh() -> Optional[Mesh]:
-    try:
-        from jax._src import mesh as mesh_lib
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-        if mesh.empty:
-            return None
-        return mesh
-    except Exception:
-        try:
-            from jax.interpreters import pxla
-            mesh = pxla.thread_resources.env.physical_mesh
-            return None if mesh.empty else mesh
-        except Exception:
-            return None
+def _context_mesh() -> Optional[AbstractMesh]:
+    """The mesh ``jax.set_mesh`` installed around this trace, if any."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def constrain_like_params(tree):

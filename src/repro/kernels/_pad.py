@@ -14,10 +14,19 @@ executable without re-running the body.  Benchmarks and tests use
 :func:`trace_count` to assert that steady-state serving dispatches hit
 the jit cache (zero retraces); when available the event is also forwarded
 to ``jax.monitoring`` so external collectors see the same signal.
+
+It also decides **how a Pallas kernel runs**: :func:`pallas_interpret`
+picks the interpreter on the CPU backend and Mosaic lowering on the TPU,
+so no kernel entry can quietly interpret on an accelerator.  Entries the
+TPU compiler still refuses call :func:`refuse_off_cpu`, which raises
+:class:`KernelNotLowered` instead of interpreting or handing the call to
+the jnp reference.
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional
+
+import jax
 
 
 def next_multiple(x: int, m: int) -> int:
@@ -38,6 +47,49 @@ def size_class(x: int, minimum: int = 1) -> int:
     per distinct (small) batch shape.
     """
     return max(next_pow2(x), next_pow2(minimum))
+
+
+# --------------------------------------------------------------------------
+# kernel mode: interpreter on the CPU, Mosaic on the TPU
+# --------------------------------------------------------------------------
+
+class KernelNotLowered(NotImplementedError):
+    """A Pallas entry the TPU compiler refuses was called off the CPU."""
+
+
+def pallas_interpret(interpret: Optional[bool] = None) -> bool:
+    """The ``interpret`` flag for a ``pallas_call`` in this process.
+
+    An explicit ``interpret`` wins (a compile test passes ``False`` to
+    lower for a described TPU from a CPU host).  Otherwise the default
+    backend decides: the CPU runs kernels in the Pallas interpreter, the
+    TPU lowers them through Mosaic, and any other backend is an error --
+    there is no silent interpreter path on an accelerator.
+    """
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels run on the TPU (Mosaic) or the "
+                       f"CPU (interpreter), not on the {backend!r} backend")
+
+
+def refuse_off_cpu(name: str, refusal: str,
+                   interpret: Optional[bool] = None) -> bool:
+    """Guard for an entry the TPU compiler refuses.
+
+    Returns ``True`` (interpret) on the CPU; anywhere a Mosaic lowering
+    would be needed it raises :class:`KernelNotLowered` naming the entry
+    and quoting what the compiler said when the entry was compiled for a
+    v5e.
+    """
+    if pallas_interpret(interpret):
+        return True
+    raise KernelNotLowered(f"Pallas entry {name!r} does not lower for the "
+                           f"TPU; the compiler refused it with: {refusal}")
 
 
 # --------------------------------------------------------------------------

@@ -10,10 +10,21 @@ and the compacted write.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels._pad import refuse_off_cpu
+
+#: what the v5e compiler said of the (1, page_size) blocks; quoted by
+#: :func:`refuse_off_cpu` off the CPU.
+_BLOCK_REFUSAL = (
+    "ValueError: The Pallas TPU lowering currently requires that the "
+    "last two dimensions of your block shape are divisible by 8 and "
+    "128 respectively, or be equal to the respective dimensions of "
+    "the overall array.")
 
 
 def _select_kernel(vals_ref, words_ref, out_ref, cnt_ref, *, page_size):
@@ -33,7 +44,8 @@ def _select_kernel(vals_ref, words_ref, out_ref, cnt_ref, *, page_size):
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
-def bitmap_select_pallas(vals, words, page_size: int, interpret: bool = True):
+def bitmap_select_pallas(vals, words, page_size: int,
+                         interpret: Optional[bool] = None):
     """vals f32[n_pages, page_size]; words uint32[n_pages, page_size//32].
 
     Returns (compacted f32[n_pages, page_size], counts int32[n_pages, 1]).
@@ -56,5 +68,6 @@ def bitmap_select_pallas(vals, words, page_size: int, interpret: bool = True):
             jax.ShapeDtypeStruct((n, page_size), vals.dtype),
             jax.ShapeDtypeStruct((n, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=refuse_off_cpu("bitmap_select_pallas", _BLOCK_REFUSAL,
+                                 interpret),
     )(vals, words)

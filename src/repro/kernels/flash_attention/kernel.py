@@ -12,11 +12,14 @@ via ``pl.when``) and GQA is handled by the caller (K/V heads broadcast).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels._pad import pallas_interpret
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -75,7 +78,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q/k/v: [bh, seq, d] (same seq for q and kv). Returns [bh, seq, d]."""
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
@@ -101,5 +104,5 @@ def flash_attention(q, k, v, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(q, k, v)

@@ -1,6 +1,8 @@
 """Jit'd wrapper exposing flash attention over [batch, heads, seq, d]."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 
 from . import kernel as K
@@ -9,7 +11,7 @@ from . import ref as R
 
 def mha(q, k, v, causal: bool = True, use_pallas: bool = True,
         block_q: int = K.DEFAULT_BLOCK_Q, block_k: int = K.DEFAULT_BLOCK_K,
-        interpret: bool = True):
+        interpret: Optional[bool] = None):
     """q: [b, h, sq, d]; k/v: [b, h_kv, sk, d] (h_kv divides h: GQA)."""
     b, h, sq, d = q.shape
     h_kv = k.shape[1]

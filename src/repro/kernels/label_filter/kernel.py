@@ -7,17 +7,17 @@ unrolled into straight-line word ops at trace time -- no recursion, no
 interpretive dispatch on device.
 
 * ``cond_bitmap_pallas`` -- evaluate the program over the label columns'
-  interval position lists, a word tile at a time: each bit position finds
-  its run per leaf via an in-VMEM binary search (O(log |P|) per lane,
-  lane-parallel across the tile), leaf bit planes are combined by the
-  unrolled program, and bits pack to uint32 words with a power-of-two dot.
+  interval position lists: each bit position finds its run per leaf by a
+  binary search (O(log |P|) per lane, lane-parallel; XLA work), then a
+  kernel combines the leaf bit planes by the unrolled program a word tile
+  at a time and packs bits to uint32 words.
   The O(|P|) storage advantage of the RLE interval lists is preserved; the
   dense per-vertex boolean column is never materialized.
 
 * ``fused_decode_filter_bitmap_batch`` -- the filtering plane fused with
   the batched retrieval plane: miss-page delta decode (+ host-fed cached
   rows for LRU hits, which skip the on-device unpack entirely) -> neighbor
-  rank-lookup bitmap -> AND with the predicate bitmap, all in ONE dispatch.
+  bitmap tail -> AND with the predicate bitmap, all in ONE dispatch.
   "Neighbors of batch B having label L" leaves the kernel as bitmap
   planes; neither the decoded ids nor the unfiltered bitmap ever reach the
   host.
@@ -25,20 +25,33 @@ interpretive dispatch on device.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.labels import eval_program
-from repro.kernels._pad import note_trace
-from repro.kernels.pac_decode.kernel import (_bitmap_from_gather,
-                                             _bitmap_scatter,
-                                             _decode_plan_rows, _gather_rows,
-                                             _unpack_and_scan_batch)
+from repro.kernels._pad import note_trace, pallas_interpret
+from repro.kernels.pac_decode.kernel import (_bitmap_tail_pallas,
+                                             _page_deltas, _scan_rows_pallas,
+                                             fused_gather_bitmap)
 
-WORD_TILE = 64  # words per grid step = 2048 bits
+WORD_TILE = 64    # callers pad the predicate plane to this many words
+COND_TILE = 512   # output words per grid step of the cond kernel
+
+
+def _leaf_planes(pos, meta, lanes):
+    """Each label's bit plane at ``lanes``: the run holding a lane is
+    found by binary search (O(log |P|) per lane, lane-parallel), and its
+    parity against the first run's value gives the bit."""
+    leaves = []
+    for i in range(pos.shape[0]):
+        run = jnp.searchsorted(pos[i], lanes,
+                               side="right").astype(jnp.int32) - 1
+        leaves.append((meta[i, 0] ^ (run & 1)).astype(jnp.int32) == 1)
+    return leaves
 
 
 def eval_cond_bits(pos, meta, lanes, ops: Tuple[Tuple, ...]):
@@ -47,18 +60,14 @@ def eval_cond_bits(pos, meta, lanes, ops: Tuple[Tuple, ...]):
     ``pos`` int32[k, n_pos] -- each label's interval position list, padded
     with ``count`` so out-of-range lanes land in the last run; ``meta``
     int32[k, 2] = (first_value, count); ``lanes`` int32[t] -- absolute bit
-    positions.  Each label's plane is looked up once (in-VMEM binary
-    search, O(log |P|) per lane); the op stream then runs through the one
+    positions.  Each label's plane is looked up once
+    (:func:`_leaf_planes`); the op stream then runs through the one
     shared stack machine (:func:`repro.core.labels.eval_program`) over
     traced jnp planes.  Returns bool[t]; lanes >= count are forced False
     so NOT never sets bits past the row count.
     """
-    leaves = []
-    for i in range(pos.shape[0]):
-        run = jnp.searchsorted(pos[i], lanes,
-                               side="right").astype(jnp.int32) - 1
-        leaves.append((meta[i, 0] ^ (run & 1)).astype(jnp.int32) == 1)
-    return eval_program(ops, leaves) & (lanes < meta[0, 1])
+    return eval_program(ops, _leaf_planes(pos, meta, lanes)) \
+        & (lanes < meta[0, 1])
 
 
 def pack_bits(bits):
@@ -68,55 +77,60 @@ def pack_bits(bits):
     return (b << shifts).sum(axis=1, dtype=jnp.uint32)
 
 
-def _cond_kernel(pos_ref, meta_ref, out_ref, *, ops):
-    wt = pl.program_id(0)
-    lanes = wt * WORD_TILE * 32 + jnp.arange(WORD_TILE * 32, dtype=jnp.int32)
-    bits = eval_cond_bits(pos_ref[...], meta_ref[...], lanes, ops)
-    out_ref[0] = pack_bits(bits)
+def _cond_kernel(count_ref, leaf_ref, out_ref, *, ops):
+    """One word tile: leaf planes laid out ``[bit, word]`` -> program ->
+    words, packed by a sublane sum of distinct powers of two (== OR)."""
+    words = out_ref.shape[1]
+    bit = jax.lax.broadcasted_iota(jnp.int32, (32, words), 0)
+    word = pl.program_id(0) * words \
+        + jax.lax.broadcasted_iota(jnp.int32, (32, words), 1)
+    leaves = [leaf_ref[i] == 1 for i in range(leaf_ref.shape[0])]
+    bits = eval_program(ops, leaves) & (word * 32 + bit < count_ref[0])
+    out_ref[...] = jnp.sum(jnp.where(bits, jnp.left_shift(1, bit), 0),
+                           axis=0, keepdims=True)
+
+
+def _cond_words(pos, meta, n_words: int, ops: Tuple[Tuple, ...],
+                interpret: Optional[bool]):
+    """Predicate plane -> ``uint32[n_words]`` (see cond_bitmap_pallas)."""
+    k = pos.shape[0]
+    lanes = (jnp.arange(n_words, dtype=jnp.int32)[None, :] * 32
+             + jnp.arange(32, dtype=jnp.int32)[:, None])
+    leaves = jnp.stack(_leaf_planes(pos, meta, lanes)).astype(jnp.int32)
+    words = min(COND_TILE, n_words)
+    out = pl.pallas_call(
+        functools.partial(_cond_kernel, ops=ops),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(-(-n_words // words),),
+            in_specs=[pl.BlockSpec((k, 32, words),
+                                   lambda w, count: (0, 0, w))],
+            out_specs=pl.BlockSpec((1, words), lambda w, count: (0, w))),
+        out_shape=jax.ShapeDtypeStruct((1, n_words), jnp.int32),
+        interpret=pallas_interpret(interpret),
+    )(meta[0, 1:], leaves)
+    return jax.lax.bitcast_convert_type(out.reshape(n_words), jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("n_words", "ops", "interpret"))
 def cond_bitmap_pallas(pos, meta, n_words: int, ops: Tuple[Tuple, ...],
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """pos int32[k, n_pos] (padded with count), meta int32[k, 2] =
     (first_value, count), ``ops`` the static postfix program.  Returns
-    uint32[n_words]."""
+    uint32[n_words].
+
+    The per-lane binary searches run in XLA (Mosaic lowers no
+    searchsorted) into ``[k, 32, n_words]`` leaf planes, bit-major so
+    that the kernel packs each word along sublanes; the kernel evaluates
+    the program a word tile at a time.
+    """
     note_trace("cond_bitmap_pallas")
-    assert n_words % WORD_TILE == 0
-    k, n_pos = pos.shape
-    kern = functools.partial(_cond_kernel, ops=ops)
-    return pl.pallas_call(
-        kern,
-        grid=(n_words // WORD_TILE,),
-        in_specs=[
-            pl.BlockSpec((k, n_pos), lambda wt: (0, 0)),
-            pl.BlockSpec((k, 2), lambda wt: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, WORD_TILE), lambda wt: (0, wt)),
-        out_shape=jax.ShapeDtypeStruct((1, n_words), jnp.uint32),
-        interpret=interpret,
-    )(pos, meta)[0]
+    return _cond_words(pos, meta, n_words, ops, interpret)
 
 
 # --------------------------------------------------------------------------
 # fused: miss-page decode + cached rows -> neighbor bitmap AND label bitmap
 # --------------------------------------------------------------------------
-
-def _fused_filter_kernel(first_ref, mind_ref, bw_ref, woff_ref, packed_ref,
-                         count_ref, cached_ref, gidx_ref, gcount_ref,
-                         fpos_ref, fmeta_ref, words_ref, ids_ref,
-                         *, page_size, n_words, ops):
-    ids = _unpack_and_scan_batch(
-        first_ref[...], mind_ref[...], bw_ref[...], woff_ref[...],
-        packed_ref[...], count_ref[...], page_size)
-    ids_ref[...] = ids
-    full = jnp.concatenate([ids, cached_ref[...]], axis=0)
-    nbr = _bitmap_from_gather(full, gidx_ref[...], gcount_ref[0, 0],
-                              page_size, n_words)
-    lanes = jnp.arange(n_words * 32, dtype=jnp.int32)
-    bits = eval_cond_bits(fpos_ref[...], fmeta_ref[...], lanes, ops)
-    words_ref[...] = nbr & pack_bits(bits)
-
 
 @functools.partial(jax.jit, static_argnames=("page_size", "n_words", "ops",
                                              "interpret"))
@@ -125,7 +139,7 @@ def fused_decode_filter_bitmap_batch(first, min_deltas, bit_widths,
                                      gidx, gcount, fpos, fmeta,
                                      page_size: int, n_words: int,
                                      ops: Tuple[Tuple, ...],
-                                     interpret: bool = True):
+                                     interpret: Optional[bool] = None):
     """Predicate-pushdown batched retrieval, one dispatch.
 
     Same contract as ``pac_decode.kernel.fused_decode_bitmap_batch`` (miss
@@ -137,58 +151,19 @@ def fused_decode_filter_bitmap_batch(first, min_deltas, bit_widths,
     (LRU backfill by-product).
     """
     note_trace("fused_decode_filter_bitmap_batch")
-    n, n_mini = min_deltas.shape
-    max_words = packed.shape[1]
-    c = cached.shape[0]
-    t = gidx.shape[0]
-    k, n_pos = fpos.shape
-    kern = functools.partial(_fused_filter_kernel, page_size=page_size,
-                             n_words=n_words, ops=ops)
-    return pl.pallas_call(
-        kern,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),
-            pl.BlockSpec((n, n_mini), lambda i: (0, 0)),
-            pl.BlockSpec((n, n_mini), lambda i: (0, 0)),
-            pl.BlockSpec((n, n_mini), lambda i: (0, 0)),
-            pl.BlockSpec((n, max_words), lambda i: (0, 0)),
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),
-            pl.BlockSpec((c, page_size), lambda i: (0, 0)),
-            pl.BlockSpec((t,), lambda i: (0,)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((k, n_pos), lambda i: (0, 0)),
-            pl.BlockSpec((k, 2), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((n_words,), lambda i: (0,)),
-            pl.BlockSpec((n, page_size), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_words,), jnp.uint32),
-            jax.ShapeDtypeStruct((n, page_size), jnp.int32),
-        ],
-        interpret=interpret,
-    )(first, min_deltas, bit_widths, word_offsets, packed, counts, cached,
-      gidx, gcount, fpos, fmeta)
+    ids = _scan_rows_pallas(
+        first, _page_deltas(min_deltas, bit_widths, word_offsets, packed,
+                            counts, page_size), interpret)
+    full = jnp.concatenate([ids, cached], axis=0)
+    fwords = _cond_words(fpos, fmeta, n_words, ops, interpret)
+    words = _bitmap_tail_pallas(full, gidx, gcount[0, 0], n_words,
+                                interpret, fwords=fwords)
+    return words, ids
 
 
 # --------------------------------------------------------------------------
 # device-resident fused filter: page indices + resident predicate plane
 # --------------------------------------------------------------------------
-
-def _fused_gather_filter_kernel(first_ref, pos_ref, mind_ref, packed_ref,
-                                gidx_ref, gcount_ref, fwords_ref, winit_ref,
-                                words_ref, ids_ref=None,
-                                *, page_size, n_words):
-    del winit_ref  # aliased storage for words_ref; fully overwritten
-    ids = _decode_plan_rows(
-        first_ref[...], pos_ref[...], mind_ref[...], packed_ref[...])
-    if ids_ref is not None:
-        ids_ref[...] = ids
-    nbr = _bitmap_scatter(ids, gidx_ref[...], gcount_ref[0, 0], n_words)
-    words_ref[...] = nbr & fwords_ref[...]
-
 
 @functools.partial(jax.jit, static_argnames=("page_size", "n_words", "p_pad",
                                              "want_ids", "interpret"))
@@ -197,7 +172,7 @@ def fused_gather_decode_filter_bitmap_batch(first, pos, mind, packed, staged,
                                             page_size: int, n_words: int,
                                             p_pad: int,
                                             want_ids: bool = True,
-                                            interpret: bool = True):
+                                            interpret: Optional[bool] = None):
     """Device-resident predicate-pushdown retrieval, one dispatch.
 
     Same contract as ``pac_decode.kernel.fused_gather_decode_bitmap_batch``
@@ -208,42 +183,13 @@ def fused_gather_decode_filter_bitmap_batch(first, pos, mind, packed, staged,
     **device-cached bitmap plane** (``FilterPlan.device_bitmap`` -- built
     once per (engine, n_words) from the RLE interval lists, label columns
     are immutable), so the dispatch ships no label bytes and re-evaluates
-    no per-lane binary searches -- the kernel ANDs the resident plane
-    into the neighbor bitmap.  ``words_init`` is aliased to the ``words``
-    output for cross-tick buffer reuse.  Returns ``(words, ids)``
-    (``ids`` in ``idx`` order), or ``words`` alone without ``want_ids``.
+    no per-lane binary searches -- the bitmap tail ANDs each word tile of
+    the resident plane into the neighbor words.  ``words_init`` is
+    accepted for signature parity and not read.  Returns ``(words,
+    ids)`` (``ids`` in ``idx`` order), or ``words`` alone without
+    ``want_ids``.
     """
     note_trace("fused_gather_decode_filter_bitmap_batch")
-    from repro.kernels.pac_decode.kernel import _split_staged
-    idx, gidx, gcount = _split_staged(staged, p_pad)
-    g = _gather_rows(idx, first, pos, mind, packed)
-    n = idx.shape[0]
-    d = pos.shape[1]
-    max_words = packed.shape[1]
-    t = gidx.shape[0]
-    kern = functools.partial(_fused_gather_filter_kernel,
-                             page_size=page_size, n_words=n_words)
-    out_specs = [pl.BlockSpec((n_words,), lambda i: (0,))]
-    out_shape = [jax.ShapeDtypeStruct((n_words,), jnp.uint32)]
-    if want_ids:
-        out_specs.append(pl.BlockSpec((n, page_size), lambda i: (0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((n, page_size), jnp.int32))
-    out = pl.pallas_call(
-        kern,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),
-            pl.BlockSpec((n, d), lambda i: (0, 0)),
-            pl.BlockSpec((n, d), lambda i: (0, 0)),
-            pl.BlockSpec((n, max_words), lambda i: (0, 0)),
-            pl.BlockSpec((t,), lambda i: (0,)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((n_words,), lambda i: (0,)),
-            pl.BlockSpec((n_words,), lambda i: (0,)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        input_output_aliases={7: 0},
-        interpret=interpret,
-    )(*g, gidx, gcount, fwords, words_init)
-    return tuple(out) if want_ids else out[0]
+    del words_init, page_size
+    return fused_gather_bitmap(first, pos, mind, packed, staged, p_pad,
+                               n_words, want_ids, interpret, fwords=fwords)

@@ -8,8 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels._pad import note_trace
-from repro.kernels.pac_decode.kernel import (_bitmap_from_gather,
-                                             _bitmap_scatter,
+from repro.kernels.pac_decode.kernel import (_bitmap_scatter,
                                              _decode_plan_rows, _gather_rows)
 from repro.kernels.pac_decode.ref import decode_pages_ref
 
@@ -34,7 +33,7 @@ def fused_filter_batch_ref(first, min_deltas, bit_widths, word_offsets,
     ids = decode_pages_ref(first, min_deltas, bit_widths, word_offsets,
                            packed, counts, page_size).astype(jnp.int32)
     full = jnp.concatenate([ids, cached], axis=0)
-    nbr = _bitmap_from_gather(full, gidx, gcount[0, 0], page_size, n_words)
+    nbr = _bitmap_scatter(full, gidx, gcount[0, 0], n_words)
     lanes = jnp.arange(n_words * 32, dtype=jnp.int32)
     words = nbr & pack_bits(eval_cond_bits(fpos, fmeta, lanes, ops))
     return words, ids

@@ -1,127 +1,73 @@
-"""Fused delta-unpack -> prefix-scan -> page-bitmap Pallas kernels.
+"""Delta-unpack -> prefix-scan -> page-bitmap kernels (Pallas / Mosaic).
 
 TPU adaptation of the paper's BMI/SIMD decoding strategy (§4.3).  The CPU
 version breaks the serial delta dependency with PEXT-compacted bit-shift
-encodings; the TPU version breaks it with a **vectorized in-VMEM prefix
-scan** after a lane-parallel variable-shift unpack, and builds PAC bitmaps
-by lane-parallel word compares instead of serial bit appends.  The fusion
-insight is preserved: the decoded ID list never leaves VMEM in the fused
-kernel; only page bitmaps are written to HBM.
+encodings; the TPU version breaks it with a **lane-parallel prefix scan**
+in VMEM, and builds PAC bitmaps by counting (bit, word) hits of the
+requested ids a word tile at a time instead of serial bit appends.
 
 Power-of-two miniblock bit widths guarantee no packed value straddles a
 32-bit word, so the unpack is a single gather + variable shift per lane --
-the same alignment argument the paper uses for its SIMD path.
+the same alignment argument the paper uses for its SIMD path.  Mosaic
+lowers no lane gather, so the gathers (page rows, per-delta words,
+requested ids) and the elementwise unpack run in XLA inside the same jit;
+the kernels do the parts that are lane-parallel:
 
-Kernels:
-  * ``delta_decode_kernel``  -- decode a batch of delta pages to int32 IDs.
-  * ``bitmap_kernel``        -- sorted IDs -> bitmap words over a target
-                                range, OR-accumulated across ID tiles.
-  * ``fused_decode_bitmap``  -- both, without materializing IDs in HBM
-                                (single page-aligned range).
-  * ``fused_decode_bitmap_batch`` -- the batched retrieval plane's fused
-                                entry: an arbitrary deduplicated page list
-                                + merged range bounds -> one dense target
-                                bitmap, in one dispatch.  Unsorted /
-                                duplicated IDs (a page interleaves many
-                                vertices' neighbor lists) are handled by an
-                                in-kernel sort + rank lookup, which is
-                                exact under any multiplicity (sum==OR
-                                tricks are not); a TPU build would use a
-                                bitonic in-VMEM sort and the word-tiled
-                                compare of ``_bitmap_tile``.
-  * ``gather_decode_pallas`` / ``fused_gather_decode_bitmap_batch`` --
-                                the device-resident entries (PR 4): the
-                                whole column's per-delta unpack plan
-                                (``PackedPages.device_plan``) lives on
-                                device; dispatches ship only an int32
-                                page-index vector, gather rows with
-                                ``jnp.take``, decode with one
-                                ``take_along_axis`` + cumsum, and build
-                                the bitmap with the O(t) sorted scatter
-                                (``_bitmap_scatter``) instead of the
-                                O(num_targets) rank lookup.
+  * ``_scan_rows_pallas``  -- first id + inclusive row scan of the deltas
+                              (Hillis-Steele over ``pltpu.roll``), gridded
+                              over blocks of page rows.
+  * ``_bitmap_tail_pallas`` -- sorted requested ids -> target bitmap
+                              words, a 512-word tile at a time, each tile
+                              streaming only the id chunks that can hit it
+                              (scalar-prefetched work list) and counting
+                              hits with a one-hot matmul on the MXU;
+                              exact under duplicates, optionally ANDed
+                              with a predicate plane.
+
+Entries: ``gather_decode_pallas`` / ``fused_gather_decode_bitmap_batch``
+(device-resident unpack plan, the served path), ``delta_decode_pallas`` /
+``fused_decode_bitmap_batch`` (per-dispatch packed pages).  The older
+single-range ``bitmap_pallas`` and ``fused_decode_bitmap`` use (1, n)
+blocks the TPU refuses: they interpret on the CPU and raise
+:class:`repro.kernels._pad.KernelNotLowered` elsewhere.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.encoding import (DEFAULT_PAGE_SIZE, MINIBLOCK, POS_BW_MASK,
                                  POS_SHIFT_SHIFT, POS_WIDX_SHIFT)
-from repro.kernels._pad import note_trace
+from repro.kernels._pad import (next_multiple, note_trace, pallas_interpret,
+                                refuse_off_cpu)
 
-
-def _unpack_and_scan(first, min_deltas, bit_widths, word_offsets, packed,
-                     count, page_size):
-    """Shared in-kernel body: packed miniblocks -> decoded int32 IDs.
-
-    All inputs are the per-page arrays (leading page axis already sliced
-    away by the BlockSpec).  Returns ids[page_size] (positions >= count
-    hold the last valid id, keeping downstream compares harmless).
-    """
-    n_deltas = page_size - 1
-    idx = jnp.arange(n_deltas, dtype=jnp.int32)
-    mini = idx // MINIBLOCK
-    within = idx % MINIBLOCK
-    bw = jnp.take(bit_widths, mini).astype(jnp.int32)
-    woff = jnp.take(word_offsets, mini)
-    # lane-parallel unpack: value i of a miniblock lives at bit
-    # (within * bw) of the miniblock's word region -- never straddles words
-    bit_pos = within * bw
-    word_idx = woff + bit_pos // 32
-    shift = (bit_pos % 32).astype(jnp.uint32)
-    words = jnp.take(packed, word_idx)
-    mask = jnp.where(bw >= 32, jnp.uint32(0xFFFFFFFF),
-                     (jnp.uint32(1) << bw.astype(jnp.uint32)) - 1)
-    resid = ((words >> shift) & mask).astype(jnp.int32)
-    resid = jnp.where(bw == 0, 0, resid)
-    deltas = resid + jnp.take(min_deltas, mini)
-    deltas = jnp.where(idx < count - 1, deltas, 0)
-    # the serial dependency becomes a parallel scan (TPU analogue of PEXT)
-    ids = first + jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(deltas)])
-    return ids
-
-
-def _decode_kernel(first_ref, mind_ref, bw_ref, woff_ref, packed_ref,
-                   count_ref, out_ref, *, page_size):
-    ids = _unpack_and_scan(
-        first_ref[0, 0], mind_ref[0], bw_ref[0], woff_ref[0],
-        packed_ref[0], count_ref[0, 0], page_size)
-    out_ref[0] = ids
+#: what the v5e compiler said of the entries that still use (1, n)
+#: blocks; quoted by :func:`refuse_off_cpu` off the CPU.
+_BLOCK_REFUSAL = (
+    "ValueError: The Pallas TPU lowering currently requires that the "
+    "last two dimensions of your block shape are divisible by 8 and "
+    "128 respectively, or be equal to the respective dimensions of "
+    "the overall array.")
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def delta_decode_pallas(first, min_deltas, bit_widths, word_offsets, packed,
                         counts, page_size: int = DEFAULT_PAGE_SIZE,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """Decode a batch of pages.
 
     Shapes: first/counts int32[n,1]; min_deltas/bit_widths/word_offsets
     int32[n, n_mini]; packed uint32[n, max_words].  Returns int32[n, page_size].
     """
     note_trace("delta_decode_pallas")
-    n, n_mini = min_deltas.shape
-    max_words = packed.shape[1]
-    kern = functools.partial(_decode_kernel, page_size=page_size)
-    return pl.pallas_call(
-        kern,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_mini), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_mini), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_mini), lambda i: (i, 0)),
-            pl.BlockSpec((1, max_words), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, page_size), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, page_size), jnp.int32),
-        interpret=interpret,
-    )(first, min_deltas, bit_widths, word_offsets, packed, counts)
+    return _scan_rows_pallas(
+        first, _page_deltas(min_deltas, bit_widths, word_offsets, packed,
+                            counts, page_size), interpret)
 
 
 # --------------------------------------------------------------------------
@@ -167,7 +113,8 @@ def _bitmap_kernel(ids_ref, count_ref, base_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("n_words", "interpret"))
-def bitmap_pallas(ids, count, base, n_words: int, interpret: bool = True):
+def bitmap_pallas(ids, count, base, n_words: int,
+                  interpret: Optional[bool] = None):
     """Sorted int32 ids -> uint32[n_words] bitmap for range starting at
     ``base`` (bit j of word w <=> id == base + 32*w + j).
 
@@ -187,7 +134,8 @@ def bitmap_pallas(ids, count, base, n_words: int, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((1, WORD_TILE), lambda it, wt: (0, wt)),
         out_shape=jax.ShapeDtypeStruct((1, n_words), jnp.uint32),
-        interpret=interpret,
+        interpret=refuse_off_cpu("bitmap_pallas", _BLOCK_REFUSAL,
+                                 interpret),
     )(ids.reshape(1, -1), count.reshape(1, 1), base.reshape(1, 1))[0]
 
 
@@ -203,9 +151,9 @@ def _fused_kernel(first_ref, mind_ref, bw_ref, woff_ref, packed_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    ids = _unpack_and_scan(
-        first_ref[0, 0], mind_ref[0], bw_ref[0], woff_ref[0],
-        packed_ref[0], count_ref[0, 0], page_size)
+    ids = first_ref[0, 0] + jnp.cumsum(_page_deltas(
+        mind_ref[...], bw_ref[...], woff_ref[...], packed_ref[...],
+        count_ref[...], page_size)[0])
     count = count_ref[0, 0]
     gidx = jnp.arange(page_size, dtype=jnp.int32)
     valid = gidx < count
@@ -221,15 +169,15 @@ def _fused_kernel(first_ref, mind_ref, bw_ref, woff_ref, packed_ref,
     out_ref[0] |= contrib.sum(axis=0, dtype=jnp.uint32)
 
 
-def _unpack_and_scan_batch(first, min_deltas, bit_widths, word_offsets,
-                           packed, counts, page_size):
-    """All pages' packed miniblocks -> decoded int32 IDs, one shot.
+def _page_deltas(min_deltas, bit_widths, word_offsets, packed, counts,
+                 page_size):
+    """All pages' packed miniblocks -> per-position deltas, one shot.
 
-    Batched (leading page axis kept) version of :func:`_unpack_and_scan`:
-    every step is an elementwise op, a row-gather, or a row-wise cumsum,
-    so the whole page stack decodes in a single vectorized pass.  Returns
-    ``ids[n_pages, page_size]`` (positions >= count hold the running last
-    id -- downstream consumers mask by count / row validity).
+    Every step is an elementwise op or a row-gather (XLA work -- Mosaic
+    has no lane gather).  Column 0 is a
+    zero delta and deltas past ``count - 1`` are zeroed, so an inclusive
+    row scan plus ``first`` yields ``ids[n_pages, page_size]`` with
+    positions >= count holding the running last id.
     """
     n = min_deltas.shape[0]
     n_deltas = page_size - 1
@@ -249,45 +197,7 @@ def _unpack_and_scan_batch(first, min_deltas, bit_widths, word_offsets,
     resid = jnp.where(bw == 0, 0, resid)
     deltas = resid + jnp.take(min_deltas, mini, axis=1)
     deltas = jnp.where(idx[None, :] < counts - 1, deltas, 0)
-    return first + jnp.concatenate(
-        [jnp.zeros((n, 1), jnp.int32), jnp.cumsum(deltas, axis=1)], axis=1)
-
-
-def _bitmap_from_gather(ids, gidx, gcount, page_size, n_words):
-    """Shared fused tail: decoded page matrix -> dense target bitmap.
-
-    ``gidx`` holds the flat (block_row * page_size + offset) position of
-    every requested row (zero-padded past ``gcount``) -- the host knows
-    the requested-row *positions* without ever seeing the decoded ids.
-    The requested ids are gathered, sorted with an out-of-range sentinel
-    for the padding, and bit ``t`` of the output is set iff some sorted id
-    equals ``t`` (rank lookup) -- exact under duplicate and unsorted ids,
-    and O(total + targets) instead of a per-target scatter (slow on every
-    backend here) or a full-page-matrix pass.
-    """
-    n_slots = n_words * 32
-    flat = jnp.take(ids.reshape(-1), gidx, mode="clip")
-    k = jnp.arange(gidx.shape[0], dtype=jnp.int32)
-    s = jnp.sort(jnp.where(k < gcount, flat, n_slots))
-    targets = jnp.arange(n_slots, dtype=jnp.int32)
-    pos = jnp.searchsorted(s, targets, side="left")
-    hit = jnp.take(s, pos, mode="clip") == targets
-    bits = hit.astype(jnp.uint32).reshape(n_words, 32)
-    shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
-    return (bits << shifts).sum(axis=1, dtype=jnp.uint32)
-
-
-def _fused_batch_kernel(first_ref, mind_ref, bw_ref, woff_ref, packed_ref,
-                        count_ref, cached_ref, gidx_ref, gcount_ref,
-                        words_ref, ids_ref, *, page_size, n_words):
-    ids = _unpack_and_scan_batch(
-        first_ref[...], mind_ref[...], bw_ref[...], woff_ref[...],
-        packed_ref[...], count_ref[...], page_size)
-    ids_ref[...] = ids
-    full = jnp.concatenate([ids, cached_ref[...]], axis=0)
-    words_ref[...] = _bitmap_from_gather(full, gidx_ref[...],
-                                         gcount_ref[0, 0], page_size,
-                                         n_words)
+    return jnp.concatenate([jnp.zeros((n, 1), jnp.int32), deltas], axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "n_words",
@@ -295,7 +205,7 @@ def _fused_batch_kernel(first_ref, mind_ref, bw_ref, woff_ref, packed_ref,
 def fused_decode_bitmap_batch(first, min_deltas, bit_widths, word_offsets,
                               packed, counts, cached, gidx, gcount,
                               page_size: int, n_words: int,
-                              interpret: bool = True):
+                              interpret: Optional[bool] = None):
     """Deduplicated page list + requested-row positions -> target bitmap.
 
     One dispatch for the whole batch: batched unpack->scan decode of the
@@ -312,37 +222,13 @@ def fused_decode_bitmap_batch(first, min_deltas, bit_widths, word_offsets,
     they simply skip the host transfer when no cache is attached).
     """
     note_trace("fused_decode_bitmap_batch")
-    n, n_mini = min_deltas.shape
-    max_words = packed.shape[1]
-    c = cached.shape[0]
-    t = gidx.shape[0]
-    kern = functools.partial(_fused_batch_kernel, page_size=page_size,
-                             n_words=n_words)
-    return pl.pallas_call(
-        kern,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),
-            pl.BlockSpec((n, n_mini), lambda i: (0, 0)),
-            pl.BlockSpec((n, n_mini), lambda i: (0, 0)),
-            pl.BlockSpec((n, n_mini), lambda i: (0, 0)),
-            pl.BlockSpec((n, max_words), lambda i: (0, 0)),
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),
-            pl.BlockSpec((c, page_size), lambda i: (0, 0)),
-            pl.BlockSpec((t,), lambda i: (0,)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((n_words,), lambda i: (0,)),
-            pl.BlockSpec((n, page_size), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_words,), jnp.uint32),
-            jax.ShapeDtypeStruct((n, page_size), jnp.int32),
-        ],
-        interpret=interpret,
-    )(first, min_deltas, bit_widths, word_offsets, packed, counts, cached,
-      gidx, gcount)
+    ids = _scan_rows_pallas(
+        first, _page_deltas(min_deltas, bit_widths, word_offsets, packed,
+                            counts, page_size), interpret)
+    full = jnp.concatenate([ids, cached], axis=0)
+    words = _bitmap_tail_pallas(full, gidx, gcount[0, 0], n_words,
+                                interpret)
+    return words, ids
 
 
 # --------------------------------------------------------------------------
@@ -378,16 +264,19 @@ def _row_cumsum(a, chunk=128):
     return (within + carry[:, :, None]).reshape(n, -1)[:, :d]
 
 
-def _decode_plan_rows(first, pos, mind, packed):
-    """Decode gathered unpack-plan rows (``PackedPages.unpack_plan``).
+def _plan_deltas(pos, mind, packed):
+    """Gathered unpack-plan rows (``PackedPages.unpack_plan``) -> the
+    per-position deltas of each page, ``int32[n, page_size]``.
 
     The per-delta expansion folded every query-independent decision
     (miniblock lookup, zero-width handling, count clamping) into the
     plan at column-build time: ``pos`` packs word index / shift /
-    effective bit width into one int32 lane, so the in-dispatch decode
-    is one gather + a few elementwise ops + the prefix scan.  Positions
-    >= count hold the running last id, exactly like
-    :func:`_unpack_and_scan_batch`.
+    effective bit width into one int32 lane, so this is one gather + a
+    few elementwise ops.  Column 0 is a zero delta (the page's first id
+    is ``first``), so an inclusive row scan yields the ids directly.
+    The word gather is a per-row lane gather, which Mosaic does not
+    lower: it runs in XLA, fused with the page-row gather, and only the
+    scan is a kernel.
     """
     word_idx = pos >> POS_WIDX_SHIFT
     shift = ((pos >> POS_SHIFT_SHIFT) & 31).astype(jnp.uint32)
@@ -396,24 +285,65 @@ def _decode_plan_rows(first, pos, mind, packed):
                      (jnp.uint32(1) << bw) - 1)
     words = jnp.take_along_axis(packed, word_idx, axis=1, mode="clip")
     resid = ((words >> shift) & mask).astype(jnp.int32)
-    deltas = resid + mind
-    n = first.shape[0]
-    return first + jnp.concatenate(
-        [jnp.zeros((n, 1), jnp.int32), _row_cumsum(deltas)], axis=1)
+    return jnp.concatenate(
+        [jnp.zeros((pos.shape[0], 1), jnp.int32), resid + mind], axis=1)
+
+
+def _decode_plan_rows(first, pos, mind, packed):
+    """Decode gathered unpack-plan rows with jnp (the reference engine).
+
+    Positions >= count hold the running last id, exactly like the
+    per-dispatch pack path's decode.
+    """
+    return first + _row_cumsum(_plan_deltas(pos, mind, packed))
+
+
+def _scan_kernel(first_ref, d_ref, out_ref):
+    """Inclusive row prefix sum + page base, lane-parallel.
+
+    A Hillis-Steele scan over the lane axis: ``log2(width)`` steps of a
+    lane rotation (``pltpu.roll``) and a masked add, all in VMEM.
+    """
+    x = d_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    s = 1
+    while s < x.shape[1]:
+        x = x + jnp.where(lane >= s, pltpu.roll(x, s, 1), 0)
+        s *= 2
+    out_ref[...] = x + first_ref[...]
+
+
+def _scan_rows_pallas(first, deltas, interpret):
+    """``first + inclusive_cumsum(deltas, axis=1)`` as a Mosaic kernel,
+    gridded over blocks of page rows (lanes padded to a multiple of 128
+    with zero deltas, which leave every prefix unchanged)."""
+    n, d = deltas.shape
+    w = next_multiple(d, 128)
+    if w != d:
+        deltas = jnp.pad(deltas, ((0, 0), (0, w - d)))
+    rows = next((r for r in (64, 32, 16, 8) if n % r == 0), n)
+    ids = pl.pallas_call(
+        _scan_kernel,
+        grid=(n // rows,),
+        in_specs=[pl.BlockSpec((rows, 1), lambda i: (i, 0)),
+                  pl.BlockSpec((rows, w), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((rows, w), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, w), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=pallas_interpret(interpret),
+    )(first, deltas)
+    return ids[:, :d] if w != d else ids
 
 
 def _bitmap_scatter(ids, gidx, gcount, n_words):
-    """Resident fused tail: requested rows -> bitmap, O(t log t).
+    """Reference bitmap tail: requested rows -> bitmap, O(t log t).
 
     Sorts the ``gcount`` requested ids (padding sorts past the range
     sentinel), drops duplicates via the sorted-neighbor compare, and
     scatter-ORs one bit per distinct id (``sum`` of distinct powers of
-    two == OR).  Replaces the O(num_targets) rank lookup of
-    :func:`_bitmap_from_gather` on the resident path -- the dense
-    searchsorted over every target id was a fixed per-dispatch cost the
-    batch size never amortized.  A TPU build would keep the rank lookup
-    (VMEM scatter is lane-hostile); on CPU/interpret the scatter wins
-    and both produce identical words.
+    two == OR).  The jnp engine's tail; the Pallas entries build the
+    same words with :func:`_bitmap_tail_pallas`.
     """
     n_slots = n_words * 32
     flat = jnp.take(ids.reshape(-1), gidx, mode="clip")
@@ -428,15 +358,155 @@ def _bitmap_scatter(ids, gidx, gcount, n_words):
         jnp.where(valid, bit, jnp.uint32(0)), mode="drop")
 
 
-def _gather_decode_kernel(first_ref, pos_ref, mind_ref, packed_ref, out_ref):
-    out_ref[...] = _decode_plan_rows(
-        first_ref[...], pos_ref[...], mind_ref[...], packed_ref[...])
+# --------------------------------------------------------------------------
+# bitmap tail: sorted requested ids -> target bitmap words, a word tile
+# at a time
+# --------------------------------------------------------------------------
+
+TAIL_WORDS = 512            # output words per tile (16384 target ids)
+TAIL_CHUNK = 1024           # sorted ids per step, as one (8, 128) block
+_FIRST, _LAST, _VALID = 1, 2, 4
+
+
+def _tail_work(s, n_tiles, tile_slots):
+    """The tail kernel's work list, built in XLA from the sorted ids.
+
+    Tile ``w`` owns the sorted ids ``s[seg[w]:seg[w+1]]`` and so the id
+    chunks ``c0[w]..c1[w]`` (at least one, so every tile is written).
+    Listing each tile's chunks in tile order gives at most
+    ``n_tiles + n_chunks`` (tile, chunk) steps -- a static grid whose
+    output block changes only between tiles, so each tile's words stay
+    in VMEM while its chunks stream in.  Returns the scalar-prefetch
+    arrays ``(seg, tile << 3 | flags, chunk)``; steps past the real
+    list are marked invalid and do nothing.
+    """
+    n_chunks = s.shape[0] // TAIL_CHUNK
+    bounds = jnp.arange(n_tiles + 1, dtype=jnp.int32) * tile_slots
+    seg = jnp.searchsorted(s, bounds, side="left").astype(jnp.int32)
+    lo, hi = seg[:-1], seg[1:]
+    c0 = jnp.minimum(lo // TAIL_CHUNK, n_chunks - 1)
+    c1 = jnp.where(hi > lo, (hi - 1) // TAIL_CHUNK, c0)
+    cnt = c1 - c0 + 1
+    end = jnp.cumsum(cnt)
+    start = end - cnt
+    step = jnp.arange(n_tiles + n_chunks, dtype=jnp.int32)
+    tile = jnp.minimum(jnp.searchsorted(end, step, side="right"),
+                       n_tiles - 1).astype(jnp.int32)
+    valid = step < end[-1]
+    chunk = jnp.where(valid, c0[tile] + step - start[tile], c1[-1])
+    flags = (jnp.where(valid & (step == start[tile]), _FIRST, 0)
+             | jnp.where(valid & (step == end[tile] - 1), _LAST, 0)
+             | jnp.where(valid, _VALID, 0))
+    return seg, (tile << 3) | flags, chunk
+
+
+def _tail_kernel(seg_ref, tf_ref, ch_ref, s_ref, *refs, n_words, filtered):
+    """One (tile, chunk) step: count each (bit, word) pair of the tile
+    that the chunk's ids hit, then fold the counts into words.
+
+    The counts are a one-hot matmul on the MXU -- ``A[bit, id] @
+    W[word, id]^T`` -- so duplicates and any id order are exact (a word
+    bit is set iff its count is > 0), and ids outside the tile match no
+    word row.  Rows of the chunk outside the tile's id segment are
+    skipped on scalar bounds.
+    """
+    if filtered:
+        f_ref, out_ref, acc_ref = refs
+    else:
+        out_ref, acc_ref = refs
+    words = out_ref.shape[1]
+    i = pl.program_id(0)
+    tf = tf_ref[i]
+    tile = tf >> 3
+
+    @pl.when((tf & _FIRST) != 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when((tf & _VALID) != 0)
+    def _count():
+        base = tile * (words * 32)
+        lo, hi = seg_ref[tile], seg_ref[tile + 1]
+        c = ch_ref[i]
+        bit_row = jax.lax.broadcasted_iota(jnp.int32, (32, 128), 0)
+        word_row = jax.lax.broadcasted_iota(jnp.int32, (words, 128), 0)
+        for r in range(TAIL_CHUNK // 128):
+            g0 = c * TAIL_CHUNK + r * 128
+
+            @pl.when((g0 < hi) & (g0 + 128 > lo))
+            def _row():
+                rel = s_ref[r:r + 1, :] - base                # [1, 128]
+                a = (bit_row == (rel & 31)).astype(jnp.bfloat16)
+                w = (word_row == (rel >> 5)).astype(jnp.bfloat16)
+                acc_ref[...] += jax.lax.dot_general(
+                    a, w, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+    @pl.when((tf & _LAST) != 0)
+    def _emit():
+        shifts = jax.lax.broadcasted_iota(jnp.int32, (32, words), 0)
+        bits = jnp.where(acc_ref[...] > 0, jnp.left_shift(1, shifts), 0)
+        out = jnp.sum(bits, axis=0, keepdims=True)  # distinct bits: == OR
+        out_ref[...] = out & f_ref[...] if filtered else out
+
+
+def _bitmap_tail_pallas(ids, gidx, gcount, n_words, interpret,
+                        fwords=None):
+    """Pallas bitmap tail: the same words as :func:`_bitmap_scatter`
+    (ANDed with ``fwords`` when given), without a scatter.
+
+    XLA gathers the requested ids, sorts them (padding becomes a
+    sentinel past every tile) and builds the work list; the kernel then
+    walks the word tiles, streaming in only the id chunks that can hit
+    each tile.
+    """
+    flat = jnp.take(ids.reshape(-1), gidx, mode="clip")
+    t = gidx.shape[0]
+    t_pad = next_multiple(t, TAIL_CHUNK)
+    k = jnp.arange(t_pad, dtype=jnp.int32)
+    flat = jnp.pad(flat, (0, t_pad - t))
+    words = min(TAIL_WORDS, n_words)
+    n_tiles = -(-n_words // words)
+    sentinel = n_tiles * words * 32
+    s = jnp.sort(jnp.where(k < gcount, flat, sentinel))
+    seg, tf, chunk = _tail_work(s, n_tiles, words * 32)
+    tile_spec = pl.BlockSpec((1, words), lambda i, seg, tf, ch: (0, tf[i] >> 3))
+    in_specs = [pl.BlockSpec((8, 128), lambda i, seg, tf, ch: (ch[i], 0))]
+    args = [s.reshape(-1, 128)]
+    if fwords is not None:
+        in_specs.append(tile_spec)
+        args.append(jax.lax.bitcast_convert_type(fwords, jnp.int32)
+                    .reshape(1, n_words))
+    kern = functools.partial(_tail_kernel, n_words=n_words,
+                             filtered=fwords is not None)
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(tf.shape[0],),
+            in_specs=in_specs,
+            out_specs=tile_spec,
+            scratch_shapes=[pltpu.VMEM((32, words), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((1, n_words), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=pallas_interpret(interpret),
+    )(seg, tf, chunk, *args)
+    return jax.lax.bitcast_convert_type(out.reshape(n_words), jnp.uint32)
+
+
+def _decode_gathered(g, interpret):
+    """Gathered plan rows -> decoded ``int32[n, page_size]`` page matrix
+    (XLA word gather + unpack, Mosaic row scan)."""
+    first, pos, mind, packed = g
+    return _scan_rows_pallas(first, _plan_deltas(pos, mind, packed),
+                             interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def gather_decode_pallas(first, pos, mind, packed, idx,
                          page_size: int = DEFAULT_PAGE_SIZE,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """Decode an arbitrary page subset of a device-resident column.
 
     Inputs are the column's device unpack plan
@@ -446,35 +516,9 @@ def gather_decode_pallas(first, pos, mind, packed, idx,
     page 0 and are sliced off by the caller).
     """
     note_trace("gather_decode")
-    g = _gather_rows(idx, first, pos, mind, packed)
-    n = idx.shape[0]
-    d = pos.shape[1]
-    max_words = packed.shape[1]
-    return pl.pallas_call(
-        _gather_decode_kernel,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),
-            pl.BlockSpec((n, d), lambda i: (0, 0)),
-            pl.BlockSpec((n, d), lambda i: (0, 0)),
-            pl.BlockSpec((n, max_words), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((n, page_size), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, page_size), jnp.int32),
-        interpret=interpret,
-    )(*g)
-
-
-def _fused_gather_kernel(first_ref, pos_ref, mind_ref, packed_ref,
-                         gidx_ref, gcount_ref, winit_ref,
-                         words_ref, ids_ref=None, *, page_size, n_words):
-    del winit_ref  # aliased storage for words_ref; fully overwritten
-    ids = _decode_plan_rows(
-        first_ref[...], pos_ref[...], mind_ref[...], packed_ref[...])
-    if ids_ref is not None:
-        ids_ref[...] = ids
-    words_ref[...] = _bitmap_scatter(ids, gidx_ref[...], gcount_ref[0, 0],
-                                     n_words)
+    del page_size  # implied by the plan's per-delta shape
+    return _decode_gathered(_gather_rows(idx, first, pos, mind, packed),
+                            interpret)
 
 
 def _split_staged(staged, p_pad):
@@ -486,6 +530,19 @@ def _split_staged(staged, p_pad):
     return idx, gidx, gcount
 
 
+def fused_gather_bitmap(first, pos, mind, packed, staged, p_pad, n_words,
+                        want_ids, interpret, fwords=None):
+    """Shared body of the resident fused entries (plain and filtered):
+    gather + decode the staged pages, then the bitmap tail, ANDed with
+    the resident predicate plane ``fwords`` when given."""
+    idx, gidx, gcount = _split_staged(staged, p_pad)
+    ids = _decode_gathered(_gather_rows(idx, first, pos, mind, packed),
+                           interpret)
+    words = _bitmap_tail_pallas(ids, gidx, gcount[0, 0], n_words,
+                                interpret, fwords=fwords)
+    return (words, ids) if want_ids else words
+
+
 @functools.partial(jax.jit, static_argnames=("page_size", "n_words", "p_pad",
                                              "want_ids", "interpret"))
 def fused_gather_decode_bitmap_batch(first, pos, mind, packed, staged,
@@ -493,7 +550,7 @@ def fused_gather_decode_bitmap_batch(first, pos, mind, packed, staged,
                                      page_size: int, n_words: int,
                                      p_pad: int,
                                      want_ids: bool = True,
-                                     interpret: bool = True):
+                                     interpret: Optional[bool] = None):
     """Device-resident fused path: page indices -> target bitmap.
 
     Same bitmap contract as :func:`fused_decode_bitmap_batch`, but the
@@ -506,62 +563,30 @@ def fused_gather_decode_bitmap_batch(first, pos, mind, packed, staged,
     -- with the column resident, re-decoding LRU-hit pages on device is
     cheaper than shipping their decoded rows across PCIe, and the
     IOMeter convention is untouched (misses charged host-side, hits
-    free).  The bitmap tail is the O(t) sorted scatter
-    (:func:`_bitmap_scatter`) instead of the O(num_targets) rank
-    lookup.  ``words_init`` (uint32[n_words]) is aliased to the bitmap
-    output, so serving ticks can hand the previous tick's plane back in
-    and reuse the device buffer instead of allocating per dispatch.
+    free).  The bitmap tail walks the target words a tile at a time
+    (:func:`_bitmap_tail_pallas`), so its cost follows the requested
+    rows plus one step per word tile.  ``words_init`` (uint32[n_words])
+    is accepted for signature parity with the dispatch layer's plane
+    pool and not read: the words are a fresh output.
 
     With ``want_ids`` the decoded page matrix is emitted as a second
     output (rows follow ``idx`` order -- miss backfill indexes by
     position in the page list) and ``(words, ids)`` is returned.  The
     matrix is only ever needed to backfill the decoded-page LRU, so
     callers with no cache attached -- and warm steady-state ticks with
-    zero misses -- pass ``want_ids=False``: the ids then never leave
-    VMEM (the original fusion contract) and the kernel skips
-    materializing page_size * n_pages ints per dispatch, which is a
-    large share of its fixed cost.  Returns ``words`` alone in that
-    case.
+    zero misses -- pass ``want_ids=False`` and get ``words`` alone.
     """
     note_trace("fused_gather_decode_bitmap_batch")
-    idx, gidx, gcount = _split_staged(staged, p_pad)
-    g = _gather_rows(idx, first, pos, mind, packed)
-    n = idx.shape[0]
-    d = pos.shape[1]
-    max_words = packed.shape[1]
-    t = gidx.shape[0]
-    kern = functools.partial(_fused_gather_kernel, page_size=page_size,
-                             n_words=n_words)
-    out_specs = [pl.BlockSpec((n_words,), lambda i: (0,))]
-    out_shape = [jax.ShapeDtypeStruct((n_words,), jnp.uint32)]
-    if want_ids:
-        out_specs.append(pl.BlockSpec((n, page_size), lambda i: (0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((n, page_size), jnp.int32))
-    out = pl.pallas_call(
-        kern,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),
-            pl.BlockSpec((n, d), lambda i: (0, 0)),
-            pl.BlockSpec((n, d), lambda i: (0, 0)),
-            pl.BlockSpec((n, max_words), lambda i: (0, 0)),
-            pl.BlockSpec((t,), lambda i: (0,)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((n_words,), lambda i: (0,)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        input_output_aliases={6: 0},
-        interpret=interpret,
-    )(*g, gidx, gcount, words_init)
-    return tuple(out) if want_ids else out[0]
+    del words_init, page_size
+    return fused_gather_bitmap(first, pos, mind, packed, staged, p_pad,
+                               n_words, want_ids, interpret)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("page_size", "words_out", "interpret"))
 def fused_decode_bitmap(first, min_deltas, bit_widths, word_offsets, packed,
                         counts, base, page_size: int, words_out: int,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """All pages' deltas -> one uint32[words_out] bitmap (base-relative)."""
     n, n_mini = min_deltas.shape
     max_words = packed.shape[1]
@@ -581,6 +606,7 @@ def fused_decode_bitmap(first, min_deltas, bit_widths, word_offsets, packed,
         ],
         out_specs=pl.BlockSpec((1, words_out), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, words_out), jnp.uint32),
-        interpret=interpret,
+        interpret=refuse_off_cpu("fused_decode_bitmap", _BLOCK_REFUSAL,
+                                 interpret),
     )(first, min_deltas, bit_widths, word_offsets, packed, counts,
       base.reshape(1, 1))[0]

@@ -684,8 +684,8 @@ def _retrieve_pac_batch_fused(col: DeltaColumn, los, his,
     by-product decode matrix backfills the cache (the one case where the
     matrix is pulled to the host).  With ``filter_plan`` (a
     :class:`repro.kernels.label_filter.ops.FilterPlan` over the target
-    vertex table) the label-predicate bitmap is ANDed into the rank-lookup
-    inside the same dispatch.
+    vertex table) the label-predicate bitmap is ANDed into the bitmap
+    tail inside the same dispatch.
 
     Two transfer regimes, identical results and accounting:
 

@@ -63,16 +63,15 @@ def fused_batch_ref(first, min_deltas, bit_widths, word_offsets, packed,
 
     Decode goes through the vmapped per-page oracle (miss pages only --
     LRU-hit rows arrive pre-decoded in ``cached``); the bitmap tail is
-    the shared rank-lookup (validated against the numpy PAC oracle in
-    tests, which is the ground truth for both engines).
+    the jnp sorted scatter shared with the resident reference.
     """
-    from .kernel import _bitmap_from_gather
+    from .kernel import _bitmap_scatter
     note_trace("fused_batch_ref")
     ids = decode_pages_ref(first, min_deltas, bit_widths, word_offsets,
                            packed, counts, page_size)
     ids = ids.astype(jnp.int32)
     full = jnp.concatenate([ids, cached], axis=0)
-    words = _bitmap_from_gather(full, gidx, gcount[0, 0], page_size, n_words)
+    words = _bitmap_scatter(full, gidx, gcount[0, 0], n_words)
     return words, ids
 
 

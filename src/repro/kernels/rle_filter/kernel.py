@@ -11,12 +11,23 @@ that the selection-pushdown kernels consume.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels._pad import refuse_off_cpu
+
 WORD_TILE = 64  # words per grid step = 2048 bits
+
+#: what the v5e compiler said of the (1, 64) output block; quoted by
+#: :func:`refuse_off_cpu` off the CPU.
+_BLOCK_REFUSAL = (
+    "ValueError: The Pallas TPU lowering currently requires that the "
+    "last two dimensions of your block shape are divisible by 8 and "
+    "128 respectively, or be equal to the respective dimensions of "
+    "the overall array.")
 
 
 def _rle_kernel(pos_ref, meta_ref, out_ref, *, n_pos):
@@ -38,7 +49,7 @@ def _rle_kernel(pos_ref, meta_ref, out_ref, *, n_pos):
 
 @functools.partial(jax.jit, static_argnames=("n_words", "interpret"))
 def rle_to_bitmap_pallas(positions, meta, n_words: int,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """positions int32[1, n_pos] (padded with ``count``), meta int32[1, 3] =
     (first_value, want, count). Returns uint32[n_words]."""
     assert n_words % WORD_TILE == 0
@@ -53,5 +64,6 @@ def rle_to_bitmap_pallas(positions, meta, n_words: int,
         ],
         out_specs=pl.BlockSpec((1, WORD_TILE), lambda wt: (0, wt)),
         out_shape=jax.ShapeDtypeStruct((1, n_words), jnp.uint32),
-        interpret=interpret,
+        interpret=refuse_off_cpu("rle_to_bitmap_pallas", _BLOCK_REFUSAL,
+                                 interpret),
     )(positions, meta)[0]

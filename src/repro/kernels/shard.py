@@ -10,8 +10,8 @@ vector, the same one-put layout as the monolithic resident path --
 under a pushed-down predicate those vectors arrive already statistics-
 pruned: partition hulls first, then per-page zone maps, so pruned
 pages never appear in any shard's staged block), and
-``shard_map`` runs the per-shard body -- gather, decode, sorted-scatter
-bitmap, optional resident-filter AND -- on every device concurrently.
+``shard_map`` runs the per-shard body -- gather, decode, bitmap tail,
+optional resident-filter AND -- on every device concurrently.
 Each shard emits a full ``[n_words]`` bitmap plane over the target id
 space; the host OR-merges the ``g`` planes into one PAC (partitions may
 contribute the same target id, so the merge is OR, not concat).
@@ -21,9 +21,9 @@ Entries are built once per static configuration and memoized
 dispatches hit the jit cache exactly like the monolithic path --
 ``note_trace`` fires only on a (re)trace.
 
-``check_rep=False`` is required: pallas_call has no replication rule
-under shard_map (the kernels never cross shards, so replication
-checking has nothing to verify anyway).
+``check_vma=False`` is required: pallas_call has no varying-manual-axes
+rule under ``jax.shard_map`` (the kernels never cross shards, so the
+check has nothing to verify anyway).
 """
 from __future__ import annotations
 
@@ -31,7 +31,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels._pad import note_trace
@@ -79,8 +78,8 @@ def sharded_fused_entry(mesh, engine: str, page_size: int, n_words: int,
 
     in_specs = (_PART,) * 5 + ((_REPL,) if filtered else ())
     out_specs = (_PART, _PART) if want_ids else _PART
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
@@ -117,13 +116,15 @@ def sharded_khop_entry(mesh, engine: str, n_out: int):
             nxt = plane * TR._filter_bits(fw, n_out) * (1 - visited)
             return (nxt, visited + nxt), nxt
 
-        (_, visited), planes = jax.lax.scan(hop, (f0, f0), filt_words)
+        # unrolled like khop_scan_ref (compile time on the TPU)
+        (_, visited), planes = jax.lax.scan(hop, (f0, f0), filt_words,
+                                             unroll=True)
         return visited[None], planes[None], planes.sum(axis=1)[None]
 
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(_PART, _PART, _REPL, _REPL),
-                             out_specs=(_PART, _PART, _PART),
-                             check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(_PART, _PART, _REPL, _REPL),
+                                 out_specs=(_PART, _PART, _PART),
+                                 check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
@@ -146,5 +147,5 @@ def sharded_decode_entry(mesh, engine: str, page_size: int, p_pad: int):
         return inner(first, pos, mind, packed, idx[0],
                      page_size=page_size)[None]
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(_PART,) * 5,
-                             out_specs=_PART, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(_PART,) * 5,
+                                 out_specs=_PART, check_vma=False))

@@ -1,4 +1,4 @@
-"""Pallas traversal kernels (interpret-mode on CPU, like pac_decode).
+"""Pallas traversal kernels (CPU interpreter only, for now).
 
 Same contracts as :mod:`repro.kernels.traversal.ref` -- the hop body
 (frontier gather through ``key_sorted`` -> bit-pack -> popcount-rank
@@ -7,22 +7,32 @@ expand -> predicate AND -> visited ANDNOT) runs inside a
 and word packing stay in the surrounding jitted entry, so k hops are
 still one dispatch with no host round-trips between hops.
 
-A TPU build would tile the rank expansion over the value id space the
-way ``_bitmap_tile`` tiles the PAC kernels; on CPU/interpret the
-single-grid body is exact and fast enough to beat the per-hop host
-loop.
+The hop bodies gather through ``key_sorted`` (and BI-2's counting hop
+scatter-adds) inside the kernel, which Mosaic does not lower, so off
+the CPU every entry raises :class:`repro.kernels._pad.KernelNotLowered`
+and the jnp engine (``engine="jax"``) is the device path.  A TPU build
+would gather in XLA and tile the rank expansion over the value id space
+the way the pac_decode bitmap tail tiles target words.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels._pad import note_trace
+from repro.kernels._pad import note_trace, refuse_off_cpu
 
 from .ref import _filter_bits, _pack_words, _seed_plane, expand_counts
+
+#: what the v5e compiler said of the hop bodies (an in-kernel 1-D
+#: gather, and BI-2's scatter-add); quoted by :func:`refuse_off_cpu`
+#: off the CPU.
+_GATHER_REFUSAL = "NotImplementedError: Only 2D gather is supported"
+_SCATTER_REFUSAL = ("NotImplementedError: Unimplemented primitive in "
+                    "Pallas TPU lowering for KernelType.TC: scatter-add")
 
 
 def _hop_kernel(ks_ref, voff_ref, f_ref, vis_ref, fw_ref, nxt_ref, *,
@@ -37,18 +47,19 @@ def _hop_kernel(ks_ref, voff_ref, f_ref, vis_ref, fw_ref, nxt_ref, *,
 
 
 def _hop_pallas(key_sorted, voff, frontier, visited, fwords, *,
-                n_out: int, interpret: bool = True):
+                n_out: int, interpret: Optional[bool] = None):
     kern = functools.partial(_hop_kernel, n_out=n_out)
     return pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct((n_out,), jnp.int32),
-        interpret=interpret,
+        interpret=refuse_off_cpu("khop_scan_pallas", _GATHER_REFUSAL,
+                                 interpret),
     )(key_sorted, voff, frontier, visited, fwords)
 
 
 @functools.partial(jax.jit, static_argnames=("n_out", "interpret"))
 def khop_scan_pallas(key_sorted, voff, seed_ids, filt_words, *,
-                     n_out: int, interpret: bool = True):
+                     n_out: int, interpret: Optional[bool] = None):
     """Fused k-hop (see :func:`...ref.khop_scan_ref`): one scan-stepped
     dispatch, the hop body a pallas kernel."""
     note_trace("khop_pallas")
@@ -60,7 +71,9 @@ def khop_scan_pallas(key_sorted, voff, seed_ids, filt_words, *,
                           n_out=n_out, interpret=interpret)
         return (nxt, visited + nxt), nxt
 
-    (_, visited), planes = jax.lax.scan(hop, (f0, f0), filt_words)
+    # unrolled like khop_scan_ref (compile time on the TPU)
+    (_, visited), planes = jax.lax.scan(hop, (f0, f0), filt_words,
+                                         unroll=True)
     return visited, planes, planes.sum(axis=1)
 
 
@@ -70,11 +83,12 @@ def _expand_kernel(ks_ref, voff_ref, f_ref, out_ref):
 
 
 def _expand_pallas(key_sorted, voff, frontier, *, n_out: int,
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     return pl.pallas_call(
         _expand_kernel,
         out_shape=jax.ShapeDtypeStruct((n_out,), jnp.int32),
-        interpret=interpret,
+        interpret=refuse_off_cpu("_expand_pallas", _GATHER_REFUSAL,
+                                 interpret),
     )(key_sorted, voff, frontier)
 
 
@@ -82,7 +96,7 @@ def _expand_pallas(key_sorted, voff, frontier, *, n_out: int,
                                              "n_words", "interpret"))
 def two_hop_pallas(ks_a, voff_a, ks_b, voff_b, seed_ids, filt_words, *,
                    n_key: int, n_mid: int, n_out: int, n_words: int,
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     """Heterogeneous two-hop chain, both expansions pallas kernels in
     one dispatch (see :func:`...ref.two_hop_ref`)."""
     note_trace("twohop_pallas")
@@ -105,7 +119,7 @@ def _count_kernel(ks_ref, voff_ref, starts_ref, ends_ref, out_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("n_key", "n_out", "interpret"))
 def count_hop_pallas(key_sorted, voff, starts, ends, *, n_key: int,
-                     n_out: int, interpret: bool = True):
+                     n_out: int, interpret: Optional[bool] = None):
     """Counting expansion (see :func:`...ref.count_hop_ref`) as one
     pallas kernel: interval frontier -> per-target edge counts."""
     note_trace("counthop_pallas")
@@ -113,5 +127,6 @@ def count_hop_pallas(key_sorted, voff, starts, ends, *, n_key: int,
     return pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct((n_out,), jnp.int32),
-        interpret=interpret,
+        interpret=refuse_off_cpu("count_hop_pallas", _SCATTER_REFUSAL,
+                                 interpret),
     )(key_sorted, voff, starts, ends)

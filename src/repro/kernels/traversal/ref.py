@@ -38,12 +38,20 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels._pad import note_trace
+from repro.kernels.pac_decode.kernel import _row_cumsum
 
 
 def _shifts():
     # built in-trace (an iota) rather than captured as a module-level
     # device constant: pallas kernel bodies cannot close over arrays
     return jnp.arange(32, dtype=jnp.uint32)
+
+
+def _cumsum(x):
+    """1-D inclusive prefix sum as a blocked scan: over millions of
+    words a flat ``jnp.cumsum`` takes the TPU compiler ~20x longer to
+    compile than 1024-wide blocks plus a carry scan."""
+    return _row_cumsum(x[None, :], chunk=1024)[0]
 
 
 def _seed_plane(seed_ids, n: int):
@@ -74,7 +82,7 @@ def expand_counts(key_sorted, voff, frontier):
              << _shifts()[None, :]).sum(axis=1, dtype=jnp.uint32)
     csw = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32),
-         jnp.cumsum(jax.lax.population_count(words).astype(jnp.int32))])
+         _cumsum(jax.lax.population_count(words).astype(jnp.int32))])
 
     def rank(i):
         w = i >> 5
@@ -109,7 +117,11 @@ def khop_scan_ref(key_sorted, voff, seed_ids, filt_words, *, n_out: int):
         nxt = plane * _filter_bits(fw, n_out) * (1 - visited)
         return (nxt, visited + nxt), nxt
 
-    (_, visited), planes = jax.lax.scan(hop, (f0, f0), filt_words)
+    # unrolled: hops are few, and with the edge gather inside a while
+    # loop the v5e compiler took ~155 s at LiveJournal size (~4 s
+    # unrolled, compile-only)
+    (_, visited), planes = jax.lax.scan(hop, (f0, f0), filt_words,
+                                         unroll=True)
     return visited, planes, planes.sum(axis=1)
 
 
@@ -148,5 +160,5 @@ def count_hop_ref(key_sorted, voff, starts, ends, *,
     note_trace("counthop_ref")
     delta = jnp.zeros((n_key + 1,), jnp.int32) \
         .at[starts].add(1, mode="drop").at[ends].add(-1, mode="drop")
-    plane = (jnp.cumsum(delta)[:n_key] > 0).astype(jnp.int32)
+    plane = (_cumsum(delta)[:n_key] > 0).astype(jnp.int32)
     return expand_counts(key_sorted, voff, plane)

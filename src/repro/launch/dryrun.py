@@ -1,8 +1,8 @@
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-NOTE: the first two executable lines below set XLA_FLAGS *before any jax
-import* (jax locks the device count at first init); they are intentionally
-ahead of every other import.
+``main`` forces 512 host devices through ``XLA_FLAGS`` before JAX first
+initializes its backends (which fixes the device count); importing the
+module changes no environment.
 
 For every assigned architecture and its supported input shapes this driver:
 
@@ -23,15 +23,9 @@ Usage:
 """
 from __future__ import annotations
 
-import os
-if "--xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=512").strip()
-
 import argparse
 import json
+import os
 import time
 import traceback
 from typing import Dict, Optional
@@ -179,7 +173,7 @@ def build_cell(cfg, shape: ShapeDef, mesh, *, batch_override: int = None,
 
 
 def _compile_cell(cfg, shape, mesh, **kw):
-    with mesh:
+    with jax.set_mesh(mesh):
         fn, args, in_sh, donate = build_cell(cfg, shape, mesh, **kw)
         jitted = jax.jit(fn, in_shardings=in_sh, donate_argnums=donate)
         return jitted.lower(*args).compile()
@@ -284,14 +278,21 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     return row
 
 
-def main() -> None:
+def main(argv=None) -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+    if "--xla_force_host_platform_device_count" not in os.environ.get(
+            "XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=512").strip()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, help="single arch id")
     ap.add_argument("--shape", default=None, help="single shape id")
     ap.add_argument("--mesh", default="both",
                     choices=["single", "multi", "both"])
     ap.add_argument("--report", default="dryrun_report.json")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     archs = [args.arch] if args.arch else list(ASSIGNED_ARCHS)
     meshes = {"single": [False], "multi": [True],

@@ -12,6 +12,8 @@ import numpy as np
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -43,12 +45,14 @@ def main() -> None:
                            max_new_tokens=args.max_new_tokens))
     t0 = time.perf_counter()
     ticks = 0
-    while eng.queue or any(s is not None for s in eng.slots):
+    while (eng.queue or any(s is not None for s in eng.slots)) \
+            and ticks <= 10_000:
         eng.step()
         ticks += 1
-        if ticks > 10_000:
-            break
     dt = time.perf_counter() - t0
+    if len(eng.finished) < args.requests:
+        raise SystemExit(f"only {len(eng.finished)} of {args.requests} "
+                         f"requests served after {ticks} ticks")
     total = args.requests * args.max_new_tokens
     print(f"served {args.requests} requests in {ticks} ticks "
           f"({eng.steps} batched decode steps, {total/dt:.1f} tok/s)")

@@ -14,6 +14,8 @@ import argparse
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=50)
@@ -27,12 +29,12 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.lower_only:
-        # the dry-run driver owns XLA device-count setup
-        import subprocess
-        import sys
-        raise SystemExit(subprocess.call(
-            [sys.executable, "-m", "repro.launch.dryrun", "--arch",
-             args.arch, "--shape", "train_4k", "--mesh", "single"]))
+        # in this process: the dry-run driver sets up its forced host
+        # devices before JAX first initializes a backend
+        from repro.launch import dryrun
+        dryrun.main(["--arch", args.arch, "--shape", "train_4k",
+                     "--mesh", "single"])
+        return
 
     import jax.numpy as jnp
     import numpy as np

@@ -179,9 +179,13 @@ class ServeEngine:
         self.queue: deque[Request] = deque()
         self.slots: List[Optional[Request]] = [None] * max_slots
         self.finished: List[Request] = []
+        # KV rows in the model's compute dtype: a wider cache promotes
+        # the attention output, and with it the layer scan's carry
+        self.cache_dtype = jnp.dtype(model.cfg.compute_dtype)
         # per-slot positions (vector index): slots advance independently
         self.cache = model.init_cache(max_slots, max_len,
-                                      dtype=jnp.float32, vector_index=True)
+                                      dtype=self.cache_dtype,
+                                      vector_index=True)
         self.slot_pos = np.zeros(max_slots, np.int32)   # python-side mirror
         self.rng = jax.random.PRNGKey(seed)
         self._decode = _decode_jit(model)
@@ -463,7 +467,7 @@ class ServeEngine:
         tmpl = self._tmp_caches.get(k)
         if tmpl is None:
             tmpl = self.model.init_cache(k, self.max_len,
-                                         dtype=jnp.float32)
+                                         dtype=self.cache_dtype)
             self._tmp_caches[k] = tmpl
         if self.faults is None:
             logits, tmp_cache = self._prefill_fn(

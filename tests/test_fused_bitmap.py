@@ -191,3 +191,35 @@ def test_pac_from_dense_bitmap_pads_tail():
     assert pac.pages() == [0]
     with pytest.raises(ValueError):
         PAC.from_dense_bitmap(words, 100)   # page_size not word-aligned
+
+
+@pytest.mark.parametrize("n_words,t,filtered", [
+    (63, 64, False),        # one partial tile, one chunk
+    (1100, 1500, False),    # three tiles (last partial), two chunks
+    (3000, 5000, True),     # chunks straddling tiles, predicate AND
+    (5000, 64, False),      # mostly empty tiles
+])
+def test_bitmap_tail_kernel_matches_scatter_reference(n_words, t, filtered):
+    """The tiled bitmap tail (sorted ids, scalar-prefetched tile/chunk
+    work list, one-hot counts) equals the jnp scatter reference under
+    duplicates, unsorted rows, tile-boundary ids and masked padding."""
+    import jax.numpy as jnp
+    from repro.kernels.pac_decode import kernel as K
+    rng = np.random.default_rng(n_words + t)
+    n_slots = n_words * 32
+    ids = rng.integers(0, n_slots, (16, 64)).astype(np.int32)
+    ids[0, :8] = [0, 31, 32, 16383, 16384, 16385, n_slots - 1, 0]
+    gidx = rng.integers(0, ids.size, t).astype(np.int32)
+    gidx[:8] = np.arange(8)
+    gcount = t - 3
+    fwords = rng.integers(0, 2 ** 32, n_words, dtype=np.uint64) \
+        .astype(np.uint32) if filtered else None
+    want = np.asarray(K._bitmap_scatter(jnp.asarray(ids), jnp.asarray(gidx),
+                                        gcount, n_words))
+    if filtered:
+        want = want & fwords
+    got = K._bitmap_tail_pallas(
+        jnp.asarray(ids), jnp.asarray(gidx), jnp.int32(gcount), n_words,
+        True, fwords=None if fwords is None else jnp.asarray(fwords))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert want.any()

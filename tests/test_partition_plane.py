@@ -171,20 +171,25 @@ def test_sharded_bit_identical_to_resident(adj_pair, engine, n_parts):
        n_parts=st.sampled_from(PART_COUNTS),
        size=st.integers(1, 96))
 @settings(max_examples=12, deadline=None)
-def test_sharded_property_random_batches(adj_pair, forced_spmd, engine,
-                                         seed, n_parts, size):
+def test_sharded_property_random_batches(adj_pair, engine, seed, n_parts,
+                                         size):
     """Satellite: hypothesis property -- sharded retrieval over random
     partition counts and random batches is bit-identical (ids + IOMeter)
-    to the single-device resident path."""
+    to the single-device resident path.  The shard_map tail is forced
+    per example (hypothesis refuses function-scoped fixtures)."""
     mono, part = adj_pair
     rng = np.random.default_rng(seed)
     vs = rng.integers(0, N, size)
     _set_parts(part, n_parts)
     m_mono, m_part = IOMeter(), IOMeter()
-    want = retrieve_neighbors_batch(mono, vs, TPS, m_mono, engine=engine,
-                                    fused=True, resident=True)
-    got = retrieve_neighbors_batch(part, vs, TPS, m_part, engine=engine,
-                                   fused=True, resident=True)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pdo, "SHARD_MIN_PAGES", 0)
+        want = retrieve_neighbors_batch(mono, vs, TPS, m_mono,
+                                        engine=engine, fused=True,
+                                        resident=True)
+        got = retrieve_neighbors_batch(part, vs, TPS, m_part,
+                                       engine=engine, fused=True,
+                                       resident=True)
     assert got == want
     assert (m_part.nbytes, m_part.nrequests) == (m_mono.nbytes,
                                                  m_mono.nrequests)

@@ -316,3 +316,21 @@ def test_pipeline_env_default(engine_parts, monkeypatch):
               "mis_speculations", "pipeline_overlap_ms", "last_tick",
               "totals"):
         assert k in s
+
+
+def test_serves_bfloat16_compute_config():
+    """Published configs compute in bfloat16: the engine's KV cache must
+    follow the compute dtype, or the attention output promotes the
+    layer scan's carry to float32 and prefill fails to trace."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    cfg = get_config("smollm-360m").reduced().with_(
+        n_units=2, param_dtype="bfloat16", compute_dtype="bfloat16")
+    model = build_model(cfg)
+    eng = ServeEngine(model, model.init(0), max_slots=2, max_len=32,
+                      eos_id=-1)
+    eng.submit(Request(0, np.arange(5, 15, dtype=np.int32),
+                       max_new_tokens=4))
+    (req,) = eng.run_until_drained(max_ticks=50)
+    assert len(req.output) == 4
+    assert all(0 <= t < cfg.vocab_size for t in req.output)
