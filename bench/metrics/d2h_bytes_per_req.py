@@ -1,0 +1,13 @@
+"""Bytes the program copied from the device per fused retrieval: its
+``transfer.d2h_bytes`` counter over its ``retrieve.requests``
+(``repro.obs``), over every retrieval of the traced run, warm-up
+included (``bench.program_trace.program_counters``)."""
+from bench import program_trace
+
+
+def read(run):
+    c = program_trace.program_counters(run) or {}
+    n = c.get("retrieve.requests", 0)
+    if n <= 0 or "transfer.d2h_bytes" not in c:
+        return None
+    return c["transfer.d2h_bytes"] / n

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import obs
 from .delta_segment import live_delta
 from .edge import AdjacencyTable
 from .pac import PAC
@@ -164,44 +165,46 @@ def retrieve_neighbors_batch(adj: AdjacencyTable, vs,
     dispatches ship page indices only -- the default, see
     ``REPRO_DEVICE_RESIDENT``) or the per-dispatch pack path.  Purely a
     transfer optimization: ids, meters, and PACs are identical."""
-    vs = np.asarray(vs, np.int64)
-    if engine == "numpy" and fused:
-        raise ValueError("fused path requires a kernel engine (jax/pallas)")
-    if vs.size == 0:
-        return PAC(target_page_size)
-    if filter is not None:
-        filter.charge(meter)
-    los, his = adj.edge_ranges_batch(vs, meter)
-    # mutable plane: the batch's pending neighbors, zone-map-pruned by
-    # the predicate's qualifying hull then exact-filtered host-side
-    # (exact, so base-side statistics pruning can never drop a delta id).
-    # RAM-resident -- no lake I/O charged.
-    delta = live_delta(adj)
-    delta_ids = None
-    if delta is not None:
-        qual = filter.qual_range() if filter is not None else None
-        delta_ids = delta.unique_ids(vs, qual)
-        if filter is not None and delta_ids.size:
-            delta_ids = delta_ids[filter.mask_ids(delta_ids, engine)]
-    if engine != "numpy" and _mirror_poisoned(adj):
-        engine = "numpy"  # graceful degradation: host oracle serves
-    if engine == "numpy":
-        qual = filter.qual_range() if filter is not None else None
-        ids = decode_edge_ranges(adj, los, his, meter, engine, qual=qual)
-        pac = PAC.from_ids(np.unique(ids), target_page_size) \
-            if ids.size else PAC(target_page_size)
+    with obs.span(obs.RETRIEVE):
+        vs = np.asarray(vs, np.int64)
+        if engine == "numpy" and fused:
+            raise ValueError(
+                "fused path requires a kernel engine (jax/pallas)")
+        if vs.size == 0:
+            return PAC(target_page_size)
         if filter is not None:
-            pac = pac.intersect(filter.pac(target_page_size))
-        if delta_ids is not None and delta_ids.size:
-            pac = pac.union(PAC.from_ids(delta_ids, target_page_size))
-        return pac
-    from repro.kernels.pac_decode import ops as pac_ops
-    return pac_ops.retrieve_pac_batch(_kernel_column(adj), los, his,
-                                      target_page_size, meter, engine=engine,
-                                      num_targets=adj.num_value_vertices,
-                                      fused=fused, label_filter=filter,
-                                      resident=resident,
-                                      delta_ids=delta_ids)
+            filter.charge(meter)
+        with obs.span(obs.EDGE_RANGES):
+            los, his = adj.edge_ranges_batch(vs, meter)
+        # mutable plane: the batch's pending neighbors, zone-map-pruned
+        # by the predicate's qualifying hull then exact-filtered
+        # host-side (exact, so base-side statistics pruning can never
+        # drop a delta id).  RAM-resident -- no lake I/O charged.
+        delta = live_delta(adj)
+        delta_ids = None
+        if delta is not None:
+            qual = filter.qual_range() if filter is not None else None
+            delta_ids = delta.unique_ids(vs, qual)
+            if filter is not None and delta_ids.size:
+                delta_ids = delta_ids[filter.mask_ids(delta_ids, engine)]
+        if engine != "numpy" and _mirror_poisoned(adj):
+            engine = "numpy"  # graceful degradation: host oracle serves
+        if engine == "numpy":
+            qual = filter.qual_range() if filter is not None else None
+            ids = decode_edge_ranges(adj, los, his, meter, engine,
+                                     qual=qual)
+            pac = PAC.from_ids(np.unique(ids), target_page_size) \
+                if ids.size else PAC(target_page_size)
+            if filter is not None:
+                pac = pac.intersect(filter.pac(target_page_size))
+            if delta_ids is not None and delta_ids.size:
+                pac = pac.union(PAC.from_ids(delta_ids, target_page_size))
+            return pac
+        from repro.kernels.pac_decode import ops as pac_ops
+        return pac_ops.retrieve_pac_batch(
+            _kernel_column(adj), los, his, target_page_size, meter,
+            engine=engine, num_targets=adj.num_value_vertices, fused=fused,
+            label_filter=filter, resident=resident, delta_ids=delta_ids)
 
 
 def retrieve_neighbors(adj: AdjacencyTable, v: int,

@@ -16,6 +16,7 @@ from typing import Dict, Iterable, List, Tuple
 
 import numpy as np
 
+from .. import obs
 from .encoding import DEFAULT_PAGE_SIZE
 
 _BIT = np.uint32(1)
@@ -203,9 +204,11 @@ class PAC:
         return sum(popcount(w) for w in self.bitmaps.values())
 
     def to_ids(self) -> np.ndarray:
-        parts = [bitmap_to_ids(self.bitmaps[p], p * self.page_size)
-                 for p in self.pages()]
-        return (np.concatenate(parts) if parts else np.zeros(0, np.int64))
+        with obs.span(obs.TO_IDS):
+            parts = [bitmap_to_ids(self.bitmaps[p], p * self.page_size)
+                     for p in self.pages()]
+            return (np.concatenate(parts) if parts
+                    else np.zeros(0, np.int64))
 
     def select(self, page_values: Dict[int, np.ndarray]) -> np.ndarray:
         """Selection pushdown: gather values whose bit is set, per page."""

@@ -12,8 +12,8 @@ calls :func:`note_trace` from inside its Python body, which only executes
 when jax actually (re)traces -- a cache hit dispatches the compiled
 executable without re-running the body.  Benchmarks and tests use
 :func:`trace_count` to assert that steady-state serving dispatches hit
-the jit cache (zero retraces); when available the event is also forwarded
-to ``jax.monitoring`` so external collectors see the same signal.
+the jit cache (zero retraces).  The counts live in the program's counter
+registry (:mod:`repro.obs`) under ``traces/<entry>``.
 
 It also decides **how a Pallas kernel runs**: :func:`pallas_interpret`
 picks the interpreter on the CPU backend and Mosaic lowering on the TPU,
@@ -27,6 +27,8 @@ from __future__ import annotations
 from typing import Dict, Optional
 
 import jax
+
+from repro import obs
 
 
 def next_multiple(x: int, m: int) -> int:
@@ -96,7 +98,7 @@ def refuse_off_cpu(name: str, refusal: str,
 # trace counting (retrace tripwire for steady-state dispatch benchmarks)
 # --------------------------------------------------------------------------
 
-_TRACES: Dict[str, int] = {}
+_TRACES = "traces/"
 
 
 def note_trace(name: str) -> None:
@@ -105,23 +107,19 @@ def note_trace(name: str) -> None:
     Call from inside the jitted function's Python body: the body runs only
     on a jit-cache miss, so the counter equals the number of traces.
     """
-    _TRACES[name] = _TRACES.get(name, 0) + 1
-    try:  # best-effort mirror into jax's own monitoring stream
-        from jax import monitoring
-        monitoring.record_event(f"/repro/kernels/trace/{name}")
-    except Exception:
-        pass
+    obs.count(_TRACES + name)
 
 
 def trace_count(prefix: str = "") -> int:
     """Total traces recorded for entries whose name starts with ``prefix``."""
-    return sum(v for k, v in _TRACES.items() if k.startswith(prefix))
+    return sum(v for k, v in trace_counts().items() if k.startswith(prefix))
 
 
 def trace_counts() -> Dict[str, int]:
     """Per-entry trace counts (a copy)."""
-    return dict(_TRACES)
+    return {k[len(_TRACES):]: v for k, v in obs.counters().items()
+            if k.startswith(_TRACES)}
 
 
 def reset_trace_counts() -> None:
-    _TRACES.clear()
+    obs.reset(_TRACES)

@@ -283,7 +283,10 @@ def _plan_deltas(pos, mind, packed):
     bw = (pos & POS_BW_MASK).astype(jnp.uint32)
     mask = jnp.where(bw >= 32, jnp.uint32(0xFFFFFFFF),
                      (jnp.uint32(1) << bw) - 1)
-    words = jnp.take_along_axis(packed, word_idx, axis=1, mode="clip")
+    # the word gather roots the decode's costliest fusion, and XLA
+    # labels a fusion with its root's scope: this one names it
+    with jax.named_scope("gather_words"):
+        words = jnp.take_along_axis(packed, word_idx, axis=1, mode="clip")
     resid = ((words >> shift) & mask).astype(jnp.int32)
     return jnp.concatenate(
         [jnp.zeros((pos.shape[0], 1), jnp.int32), resid + mind], axis=1)
@@ -536,10 +539,15 @@ def fused_gather_bitmap(first, pos, mind, packed, staged, p_pad, n_words,
     gather + decode the staged pages, then the bitmap tail, ANDed with
     the resident predicate plane ``fwords`` when given."""
     idx, gidx, gcount = _split_staged(staged, p_pad)
-    ids = _decode_gathered(_gather_rows(idx, first, pos, mind, packed),
-                           interpret)
-    words = _bitmap_tail_pallas(ids, gidx, gcount[0, 0], n_words,
-                                interpret, fwords=fwords)
+    # named scopes label each phase's device ops in the profiler trace
+    # under a name that survives XLA's renumbering of its fusions
+    with jax.named_scope("gather_rows"):
+        g = _gather_rows(idx, first, pos, mind, packed)
+    with jax.named_scope("decode"):
+        ids = _decode_gathered(g, interpret)
+    with jax.named_scope("bitmap_tail"):
+        words = _bitmap_tail_pallas(ids, gidx, gcount[0, 0], n_words,
+                                    interpret, fwords=fwords)
     return (words, ids) if want_ids else words
 
 

@@ -33,6 +33,7 @@ from collections import deque
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.core.encoding import (DeltaColumn, delta_decode_page, pack_column,
@@ -41,6 +42,7 @@ from repro.core.labels import intervals_to_ids
 from repro.core.pac import PAC
 from repro.core.page_cache import live_cache, miss_runs
 from repro.core.partition import live_partitions
+from repro import obs
 from repro.kernels._pad import next_multiple, next_pow2, size_class
 
 from . import kernel as K
@@ -153,6 +155,36 @@ def _charge_pages(col: DeltaColumn, pages: Sequence[int], meter) -> None:
         return
     meter.record(sum(col.pages[int(p)].nbytes() for p in pages),
                  miss_runs(pages))
+
+
+def _put(a: np.ndarray, sharding=None):
+    """Host-to-device put of one staged array, spanned and counted."""
+    with obs.span(obs.UPLOAD):
+        out = (jnp.asarray(a) if sharding is None
+               else jax.device_put(a, sharding))
+    obs.count("transfer.h2d_bytes", a.nbytes)
+    return out
+
+
+def _pull(a) -> np.ndarray:
+    """Device-to-host copy of one output (waits for the device first),
+    spanned and counted."""
+    with obs.span(obs.PULL):
+        out = np.asarray(a)
+    obs.count("transfer.d2h_bytes", out.nbytes)
+    return out
+
+
+def _count_dispatch(rows: int, pages: int, page_size: int) -> None:
+    """Rows a fused dispatch was asked for, and the delta lanes its
+    decode ran over (every lane of every padded page)."""
+    obs.count("retrieve.rows", rows)
+    obs.count("retrieve.lanes_decoded", pages * (page_size - 1))
+
+
+def _assemble(words: np.ndarray, target_page_size: int) -> PAC:
+    with obs.span(obs.ASSEMBLE):
+        return PAC.from_dense_bitmap(words, target_page_size)
 
 
 def _page_index_vector(pages: Sequence[int], total_pages: int) -> np.ndarray:
@@ -543,78 +575,83 @@ def _retrieve_pac_batch_sharded(col: DeltaColumn, parts, los, his, pages,
     a measurable per-dispatch cost).
     """
     ps = col.page_size
-    qual = filter_plan.qual_range() if filter_plan is not None else None
-    owner, mask = parts.prune(pages, qual)
-    if mask is not None:
-        pages = pages[mask]
-        if pages.size == 0:  # every partition statistics-pruned
-            return PAC(target_page_size)
-    # page-granular zone maps inside the surviving partitions: a finer
-    # sieve over the same hull (partition-pruned pages are a subset of
-    # page-pruned ones, so the final page set -- and the meter -- equals
-    # the monolithic path's at any partition count)
-    kept, pmask = prune_page_list(col, pages, qual)
-    if pmask is not None:
-        pages, owner = kept, owner[pmask]
-        if pages.size == 0:  # every page statistics-pruned
-            return PAC(target_page_size)
-    pruned = mask is not None or pmask is not None
-    stack_idx = _stack_index(parts, pages, owner)
-    cache = live_cache(col)
-    if cache is None:
-        hits, miss = {}, [int(p) for p in pages]
-    else:
-        hits, miss = cache.split(pages, owner=owner)
-    _charge_pages(col, miss, meter)
-    n_words = -(-num_targets // 32)
-    want_ids = cache is not None and bool(miss)
-    # requested rows: with statistics pruning, rows whose page was
-    # dropped cannot pass the predicate and are dropped with it
-    rows = intervals_to_ids((los, his))
-    n_rows = len(rows)
-    page_of = rows // ps
-    pidx = np.searchsorted(pages, page_of)
-    if pruned:
-        ok = pidx < len(pages)
-        ok &= pages[np.minimum(pidx, len(pages) - 1)] == page_of
-        if not ok.all():
-            rows, page_of, pidx = rows[ok], page_of[ok], pidx[ok]
-    g, ppd, dev_of_page, per_dev = _shard_width(parts, owner)
+    with obs.span(obs.PLAN):
+        qual = filter_plan.qual_range() if filter_plan is not None else None
+        owner, mask = parts.prune(pages, qual)
+        if mask is not None:
+            pages = pages[mask]
+            if pages.size == 0:  # every partition statistics-pruned
+                return PAC(target_page_size)
+        # page-granular zone maps inside the surviving partitions: a
+        # finer sieve over the same hull (partition-pruned pages are a
+        # subset of page-pruned ones, so the final page set -- and the
+        # meter -- equals the monolithic path's at any partition count)
+        kept, pmask = prune_page_list(col, pages, qual)
+        if pmask is not None:
+            pages, owner = kept, owner[pmask]
+            if pages.size == 0:  # every page statistics-pruned
+                return PAC(target_page_size)
+        pruned = mask is not None or pmask is not None
+        stack_idx = _stack_index(parts, pages, owner)
+        cache = live_cache(col)
+        if cache is None:
+            hits, miss = {}, [int(p) for p in pages]
+        else:
+            hits, miss = cache.split(pages, owner=owner)
+        _charge_pages(col, miss, meter)
+        n_words = -(-num_targets // 32)
+        want_ids = cache is not None and bool(miss)
+        # requested rows: with statistics pruning, rows whose page was
+        # dropped cannot pass the predicate and are dropped with it
+        rows = intervals_to_ids((los, his))
+        n_rows = len(rows)
+        page_of = rows // ps
+        pidx = np.searchsorted(pages, page_of)
+        if pruned:
+            ok = pidx < len(pages)
+            ok &= pages[np.minimum(pidx, len(pages) - 1)] == page_of
+            if not ok.all():
+                rows, page_of, pidx = rows[ok], page_of[ok], pidx[ok]
+        g, ppd, dev_of_page, per_dev = _shard_width(parts, owner)
     if g == 1:
         # single-shard tail: exactly the monolithic resident dispatch,
         # addressed through the stacked partition plan
-        arrays, _ = parts.device_plan_single(engine)
-        gidx = (pidx * ps + (rows - page_of * ps)).astype(np.int32)
-        total = len(gidx)
-        # pad to the unpruned request's class -- pruning never mints a
-        # new staged shape (see _gather_positions)
-        pad = size_class(n_rows, RANGE_CLASS_MIN) - total
-        if pad:
-            gidx = np.concatenate([gidx, np.zeros(pad, np.int32)])
-        p_pad = _page_class(len(pages), parts.stack_rows)
-        staged = np.zeros(p_pad + len(gidx) + 1, np.int32)
-        staged[:len(pages)] = stack_idx
-        staged[p_pad:-1] = gidx
-        staged[-1] = total
-        jargs = arrays + (jnp.asarray(staged),)
-        if filter_plan is None:
-            fn = (K.fused_gather_decode_bitmap_batch if engine == "pallas"
-                  else R.fused_gather_batch_ref)
-            out = fn(*jargs, _words_buffer(engine, n_words),
-                     page_size=ps, n_words=n_words, p_pad=p_pad,
-                     want_ids=want_ids)
-        else:
-            from repro.kernels.label_filter import kernel as LK
-            from repro.kernels.label_filter import ref as LR
-            fwords = filter_plan.device_bitmap(engine, n_words)
-            fn = (LK.fused_gather_decode_filter_bitmap_batch
-                  if engine == "pallas" else LR.fused_gather_filter_batch_ref)
-            out = fn(*jargs, fwords, _words_buffer(engine, n_words),
-                     page_size=ps, n_words=n_words, p_pad=p_pad,
-                     want_ids=want_ids)
+        with obs.span(obs.PLAN):
+            arrays, _ = parts.device_plan_single(engine)
+            gidx = (pidx * ps + (rows - page_of * ps)).astype(np.int32)
+            total = len(gidx)
+            # pad to the unpruned request's class -- pruning never mints
+            # a new staged shape (see _gather_positions)
+            pad = size_class(n_rows, RANGE_CLASS_MIN) - total
+            if pad:
+                gidx = np.concatenate([gidx, np.zeros(pad, np.int32)])
+            p_pad = _page_class(len(pages), parts.stack_rows)
+            staged = np.zeros(p_pad + len(gidx) + 1, np.int32)
+            staged[:len(pages)] = stack_idx
+            staged[p_pad:-1] = gidx
+            staged[-1] = total
+        jargs = arrays + (_put(staged),)
+        with obs.span(obs.LAUNCH):
+            if filter_plan is None:
+                fn = (K.fused_gather_decode_bitmap_batch
+                      if engine == "pallas" else R.fused_gather_batch_ref)
+                out = fn(*jargs, _words_buffer(engine, n_words),
+                         page_size=ps, n_words=n_words, p_pad=p_pad,
+                         want_ids=want_ids)
+            else:
+                from repro.kernels.label_filter import kernel as LK
+                from repro.kernels.label_filter import ref as LR
+                fwords = filter_plan.device_bitmap(engine, n_words)
+                fn = (LK.fused_gather_decode_filter_bitmap_batch
+                      if engine == "pallas"
+                      else LR.fused_gather_filter_batch_ref)
+                out = fn(*jargs, fwords, _words_buffer(engine, n_words),
+                         page_size=ps, n_words=n_words, p_pad=p_pad,
+                         want_ids=want_ids)
+        _count_dispatch(total, p_pad, ps)
         if want_ids:
             words, ids = out
-            mat = np.asarray(ids, np.int64)
+            mat = _pull(ids).astype(np.int64)
             pos_of = {int(p): i for i, p in enumerate(pages)}
             for p in miss:
                 i = pos_of[p]
@@ -622,42 +659,44 @@ def _retrieve_pac_batch_sharded(col: DeltaColumn, parts, los, his, pages,
                           part=int(owner[i]))
         else:
             words = out
-        host_words = np.asarray(words)
+        host_words = _pull(words)
         _pool_words(engine, n_words, words)  # reuse 2 dispatches later
-        return PAC.from_dense_bitmap(host_words, target_page_size)
+        return _assemble(host_words, target_page_size)
     # SPMD tail: bucket per device and dispatch across the mesh
-    import jax
     from jax.sharding import NamedSharding, PartitionSpec
     from repro.kernels.shard import sharded_fused_entry
-    mesh, plan, pmax = parts.device_plan(engine)
-    block0 = dev_of_page * (ppd * pmax)
-    local_idx = (stack_idx - block0).astype(np.int32)
-    # pidx already maps each row to its page's slot; gather its device
-    # from there instead of a second searchsorted over all rows
-    dev_of_row = dev_of_page[pidx]
-    dev_page_start = np.searchsorted(dev_of_page, np.arange(g))
-    base_local = pidx - dev_page_start[dev_of_row]
-    gidx = (base_local * ps + (rows - page_of * ps)).astype(np.int32)
-    row_lists = [gidx[dev_of_row == i] for i in range(g)]
-    p_pad = _page_class(int(per_dev.max()), ppd * pmax)
-    t_pad = size_class(max(len(x) for x in row_lists), RANGE_CLASS_MIN)
-    staged = np.zeros((g, p_pad + t_pad + 1), np.int32)
-    for i in range(g):
-        sel = local_idx[dev_of_page == i]
-        staged[i, :len(sel)] = sel
-        staged[i, p_pad:p_pad + len(row_lists[i])] = row_lists[i]
-        staged[i, -1] = len(row_lists[i])
-    jstaged = jax.device_put(
-        staged, NamedSharding(mesh, PartitionSpec("part", None)))
+    with obs.span(obs.PLAN):
+        mesh, plan, pmax = parts.device_plan(engine)
+        block0 = dev_of_page * (ppd * pmax)
+        local_idx = (stack_idx - block0).astype(np.int32)
+        # pidx already maps each row to its page's slot; gather its
+        # device from there instead of a second searchsorted over all rows
+        dev_of_row = dev_of_page[pidx]
+        dev_page_start = np.searchsorted(dev_of_page, np.arange(g))
+        base_local = pidx - dev_page_start[dev_of_row]
+        gidx = (base_local * ps + (rows - page_of * ps)).astype(np.int32)
+        row_lists = [gidx[dev_of_row == i] for i in range(g)]
+        p_pad = _page_class(int(per_dev.max()), ppd * pmax)
+        t_pad = size_class(max(len(x) for x in row_lists), RANGE_CLASS_MIN)
+        staged = np.zeros((g, p_pad + t_pad + 1), np.int32)
+        for i in range(g):
+            sel = local_idx[dev_of_page == i]
+            staged[i, :len(sel)] = sel
+            staged[i, p_pad:p_pad + len(row_lists[i])] = row_lists[i]
+            staged[i, -1] = len(row_lists[i])
+    jstaged = _put(staged, NamedSharding(mesh, PartitionSpec("part", None)))
     fargs = ()
     if filter_plan is not None:
         fargs = (filter_plan.device_bitmap_sharded(engine, n_words, mesh),)
     fn = sharded_fused_entry(mesh, engine, ps, n_words, p_pad, want_ids,
                              filter_plan is not None)
-    out = fn(*plan, jstaged, *fargs)
+    with obs.span(obs.LAUNCH):
+        out = fn(*plan, jstaged, *fargs)
+    # each shard decodes p_pad pages
+    _count_dispatch(len(gidx), g * p_pad, ps)
     if want_ids:
         planes, ids = out
-        mat = np.asarray(ids, np.int64)  # [g, p_pad, ps]
+        mat = _pull(ids).astype(np.int64)  # [g, p_pad, ps]
         pos = {int(p): (int(dev_of_page[i]),
                         i - int(dev_page_start[dev_of_page[i]]),
                         int(owner[i]))
@@ -667,8 +706,11 @@ def _retrieve_pac_batch_sharded(col: DeltaColumn, parts, los, his, pages,
             cache.put(p, mat[d, s, :col.pages[p].count].copy(), part=k)
     else:
         planes = out
-    merged = np.bitwise_or.reduce(np.asarray(planes, np.uint32), axis=0)
-    return PAC.from_dense_bitmap(merged, target_page_size)
+    planes = _pull(planes)
+    with obs.span(obs.ASSEMBLE):
+        # a target id may be a neighbor via several partitions
+        merged = np.bitwise_or.reduce(planes, axis=0)
+        return PAC.from_dense_bitmap(merged, target_page_size)
 
 
 def _retrieve_pac_batch_fused(col: DeltaColumn, los, his,
@@ -703,7 +745,9 @@ def _retrieve_pac_batch_fused(col: DeltaColumn, los, his,
       rows are fed in pre-decoded via the ``cached`` input.
     """
     ps = col.page_size
-    pages, _ = page_set_for_ranges(los, his, ps)
+    obs.count("retrieve.requests")
+    with obs.span(obs.PLAN):
+        pages, _ = page_set_for_ranges(los, his, ps)
     if pages.size == 0:
         return PAC(target_page_size)
     if engine not in ("jax", "pallas"):
@@ -720,122 +764,135 @@ def _retrieve_pac_batch_fused(col: DeltaColumn, los, his,
         return _retrieve_pac_batch_sharded(col, parts, los, his, pages,
                                            target_page_size, num_targets,
                                            meter, engine, filter_plan)
-    # page-granular statistics pushdown: with a predicate pushed down,
-    # pages whose zone map cannot intersect its qualifying hull drop out
-    # *before* the cache split and the staging -- never gathered onto
-    # the device, never decoded, never charged (the sharded path above
-    # applies the same sieve after its partition-level prune)
-    qual = filter_plan.qual_range() if filter_plan is not None else None
-    pages, pmask = prune_page_list(col, pages, qual)
-    if pages.size == 0:  # every page statistics-pruned
-        return PAC(target_page_size)
-    cache = live_cache(col)
-    part_of = {}
-    if cache is None:
-        hits, miss = {}, [int(p) for p in pages]
-    else:
-        # a partitioned column's LRU entries live in the (partition,
-        # page) namespace on every path -- the non-resident oracle must
-        # probe/fill the same keys the sharded dispatches use, or one
-        # column's cache splits into two disjoint namespaces
-        # (double-charging warm pages)
-        owner = parts.part_of_pages(pages) if parts is not None else None
-        if owner is not None:
-            part_of = {int(p): int(o) for p, o in zip(pages, owner)}
-        hits, miss = cache.split(pages, owner=owner)
-    _charge_pages(col, miss, meter)
-    n_words = -(-num_targets // 32)
+    with obs.span(obs.PLAN):
+        # page-granular statistics pushdown: with a predicate pushed
+        # down, pages whose zone map cannot intersect its qualifying
+        # hull drop out *before* the cache split and the staging --
+        # never gathered onto the device, never decoded, never charged
+        # (the sharded path above applies the same sieve after its
+        # partition-level prune)
+        qual = filter_plan.qual_range() if filter_plan is not None else None
+        pages, pmask = prune_page_list(col, pages, qual)
+        if pages.size == 0:  # every page statistics-pruned
+            return PAC(target_page_size)
+        cache = live_cache(col)
+        part_of = {}
+        if cache is None:
+            hits, miss = {}, [int(p) for p in pages]
+        else:
+            # a partitioned column's LRU entries live in the (partition,
+            # page) namespace on every path -- the non-resident oracle
+            # must probe/fill the same keys the sharded dispatches use,
+            # or one column's cache splits into two disjoint namespaces
+            # (double-charging warm pages)
+            owner = parts.part_of_pages(pages) if parts is not None \
+                else None
+            if owner is not None:
+                part_of = {int(p): int(o) for p, o in zip(pages, owner)}
+            hits, miss = cache.split(pages, owner=owner)
+        _charge_pages(col, miss, meter)
+        n_words = -(-num_targets // 32)
+        if resident:
+            # rows are in sorted-page order: base_of_page[i] == i
+            gidx, total = _gather_positions(pages, np.arange(len(pages)),
+                                            los, his, ps,
+                                            pruned=pmask is not None)
+            plan = pack_column(col).device_plan(engine)
+            # one staging vector [idx | gidx | total] = one device put
+            # per dispatch (three separate puts were a measurable fixed
+            # cost); page padding capped at the whole column
+            # (sharded-path ladder cap, backported to the monolithic
+            # resident dispatch)
+            p_pad = _page_class(len(pages), len(col.pages))
+            staged = np.zeros(p_pad + len(gidx) + 1, np.int32)
+            staged[:len(pages)] = pages
+            staged[p_pad:-1] = gidx
+            staged[-1] = total
     if resident:
-        # rows are in sorted-page order: base_of_page[i] == i
-        gidx, total = _gather_positions(pages, np.arange(len(pages)),
-                                        los, his, ps,
-                                        pruned=pmask is not None)
-        plan = pack_column(col).device_plan(engine)
-        # one staging vector [idx | gidx | total] = one device put per
-        # dispatch (three separate puts were a measurable fixed cost);
-        # page padding capped at the whole column (sharded-path ladder
-        # cap, backported to the monolithic resident dispatch)
-        p_pad = _page_class(len(pages), len(col.pages))
-        staged = np.zeros(p_pad + len(gidx) + 1, np.int32)
-        staged[:len(pages)] = pages
-        staged[p_pad:-1] = gidx
-        staged[-1] = total
-        jargs = plan + (jnp.asarray(staged),)
+        jargs = plan + (_put(staged),)
         # the decode matrix only exists to backfill the LRU: with no
         # cache -- or a warm one (zero misses) -- the ids never leave
         # the kernel, skipping the dominant output materialization
         want_ids = cache is not None and bool(miss)
-        if filter_plan is None:
-            fn = (K.fused_gather_decode_bitmap_batch if engine == "pallas"
-                  else R.fused_gather_batch_ref)
-            out = fn(*jargs, _words_buffer(engine, n_words),
-                     page_size=ps, n_words=n_words, p_pad=p_pad,
-                     want_ids=want_ids)
-        else:
-            from repro.kernels.label_filter import kernel as LK
-            from repro.kernels.label_filter import ref as LR
-            fwords = filter_plan.device_bitmap(engine, n_words)
-            fn = (LK.fused_gather_decode_filter_bitmap_batch
-                  if engine == "pallas" else LR.fused_gather_filter_batch_ref)
-            out = fn(*jargs, fwords, _words_buffer(engine, n_words),
-                     page_size=ps, n_words=n_words, p_pad=p_pad,
-                     want_ids=want_ids)
+        with obs.span(obs.LAUNCH):
+            if filter_plan is None:
+                fn = (K.fused_gather_decode_bitmap_batch
+                      if engine == "pallas" else R.fused_gather_batch_ref)
+                out = fn(*jargs, _words_buffer(engine, n_words),
+                         page_size=ps, n_words=n_words, p_pad=p_pad,
+                         want_ids=want_ids)
+            else:
+                from repro.kernels.label_filter import kernel as LK
+                from repro.kernels.label_filter import ref as LR
+                fwords = filter_plan.device_bitmap(engine, n_words)
+                fn = (LK.fused_gather_decode_filter_bitmap_batch
+                      if engine == "pallas"
+                      else LR.fused_gather_filter_batch_ref)
+                out = fn(*jargs, fwords, _words_buffer(engine, n_words),
+                         page_size=ps, n_words=n_words, p_pad=p_pad,
+                         want_ids=want_ids)
+        _count_dispatch(total, p_pad, ps)
         if want_ids:
             words, ids = out
-            mat = np.asarray(ids, np.int64)
+            mat = _pull(ids).astype(np.int64)
             pos_of = {int(p): i for i, p in enumerate(pages)}
             for p in miss:
                 cache.put(p, mat[pos_of[p], :col.pages[p].count].copy(),
                           part=part_of.get(p))
         else:
             words = out
-        host_words = np.asarray(words)
+        host_words = _pull(words)
         _pool_words(engine, n_words, words)  # reuse 2 dispatches later
-        return PAC.from_dense_bitmap(host_words, target_page_size)
-    m = len(miss)
-    m_pad = next_pow2(m)
-    args = pack_page_list(col, miss)
-    if m_pad - m:
-        args = tuple(np.concatenate(
-            [a, np.zeros((m_pad - m,) + a.shape[1:], a.dtype)])
-            for a in args)
-    hit_list = [int(p) for p in pages if int(p) in hits]
-    cached = np.zeros((next_pow2(len(hit_list)), ps), np.int32)
-    for i, p in enumerate(hit_list):
-        d = hits[p]
-        cached[i, :len(d)] = d
-    # matrix row of each sorted page: misses first, then cached rows
-    miss_set = set(miss)
-    is_miss = np.fromiter((int(p) in miss_set for p in pages), bool,
-                          len(pages))
-    base_of_page = np.where(is_miss, np.cumsum(is_miss) - 1,
-                            m_pad + np.cumsum(~is_miss) - 1)
-    gidx, total = _gather_positions(pages, base_of_page, los, his, ps,
-                                    pruned=pmask is not None)
-    jargs = [jnp.asarray(a) for a in args] \
-        + [jnp.asarray(cached), jnp.asarray(gidx),
-           jnp.full((1, 1), total, np.int32)]
-    if filter_plan is None:
-        if engine == "pallas":
-            words, ids = K.fused_decode_bitmap_batch(*jargs, page_size=ps,
-                                                     n_words=n_words)
+        return _assemble(host_words, target_page_size)
+    with obs.span(obs.PLAN):
+        m = len(miss)
+        m_pad = next_pow2(m)
+        args = pack_page_list(col, miss)
+        if m_pad - m:
+            args = tuple(np.concatenate(
+                [a, np.zeros((m_pad - m,) + a.shape[1:], a.dtype)])
+                for a in args)
+        hit_list = [int(p) for p in pages if int(p) in hits]
+        cached = np.zeros((next_pow2(len(hit_list)), ps), np.int32)
+        for i, p in enumerate(hit_list):
+            d = hits[p]
+            cached[i, :len(d)] = d
+        # matrix row of each sorted page: misses first, then cached rows
+        miss_set = set(miss)
+        is_miss = np.fromiter((int(p) in miss_set for p in pages), bool,
+                              len(pages))
+        base_of_page = np.where(is_miss, np.cumsum(is_miss) - 1,
+                                m_pad + np.cumsum(~is_miss) - 1)
+        gidx, total = _gather_positions(pages, base_of_page, los, his, ps,
+                                        pruned=pmask is not None)
+    jargs = [_put(a) for a in args] \
+        + [_put(cached), _put(gidx), _put(np.full((1, 1), total, np.int32))]
+    if filter_plan is not None:
+        fargs = [_put(filter_plan.pos), _put(filter_plan.meta)]
+    with obs.span(obs.LAUNCH):
+        if filter_plan is None:
+            if engine == "pallas":
+                words, ids = K.fused_decode_bitmap_batch(
+                    *jargs, page_size=ps, n_words=n_words)
+            else:
+                words, ids = R.fused_batch_ref(*jargs, page_size=ps,
+                                               n_words=n_words)
         else:
-            words, ids = R.fused_batch_ref(*jargs, page_size=ps,
-                                           n_words=n_words)
-    else:
-        from repro.kernels.label_filter import kernel as LK
-        from repro.kernels.label_filter import ref as LR
-        fargs = [jnp.asarray(filter_plan.pos), jnp.asarray(filter_plan.meta)]
-        fn = (LK.fused_decode_filter_bitmap_batch if engine == "pallas"
-              else LR.fused_filter_batch_ref)
-        words, ids = fn(*jargs, *fargs, page_size=ps, n_words=n_words,
-                        ops=filter_plan.program.ops)
+            from repro.kernels.label_filter import kernel as LK
+            from repro.kernels.label_filter import ref as LR
+            fn = (LK.fused_decode_filter_bitmap_batch if engine == "pallas"
+                  else LR.fused_filter_batch_ref)
+            words, ids = fn(*jargs, *fargs, page_size=ps, n_words=n_words,
+                            ops=filter_plan.program.ops)
+    # the miss pages, padded to m_pad, are the only ones decoded: cache
+    # hits come in decoded
+    _count_dispatch(total, m_pad, ps)
     if cache is not None and miss:
-        mat = np.asarray(ids, np.int64)
+        mat = _pull(ids).astype(np.int64)
         for i, p in enumerate(miss):
             cache.put(p, mat[i, :col.pages[p].count].copy(),
                       part=part_of.get(p))
-    return PAC.from_dense_bitmap(np.asarray(words), target_page_size)
+    return _assemble(_pull(words), target_page_size)
 
 
 def retrieve_pac_batch(col: DeltaColumn, los, his, target_page_size: int,
