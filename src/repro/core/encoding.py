@@ -47,6 +47,11 @@ POS_WIDX_SHIFT = 11
 POS_SHIFT_SHIFT = 6
 POS_BW_MASK = 63
 
+#: lanes of a row of the slab plan (see :func:`slab_plan`); a page makes
+#: whole slabs when its rows fill whole (8, 128) tiles
+SLAB_LANES = 128
+SLAB_TILE = 8 * SLAB_LANES
+
 
 # --------------------------------------------------------------------------
 # bitpacking (vectorized, power-of-two widths only)
@@ -404,11 +409,14 @@ class PackedPages:
         return self._plan
 
     def device_plan(self, engine: str) -> Tuple:
-        """Engine-keyed device mirror of the unpack plan (once each)."""
+        """Engine-keyed device mirror of the unpack plan (once each), in
+        the engine's layout (:func:`engine_plan`)."""
         plan = self._device_plans.get(engine)
         if plan is None:
             import jax.numpy as jnp
-            plan = tuple(jnp.asarray(a) for a in self.unpack_plan())
+            plan = tuple(jnp.asarray(a) for a in engine_plan(
+                self.unpack_plan(), engine,
+                self.page_size or self.packed.shape[1]))
             self._device_plans[engine] = plan
             self.device_transfers += 1
         return plan
@@ -437,6 +445,37 @@ class PackedPages:
         idx = np.asarray(pages, np.int64)
         return (self.first[idx], self.min_deltas[idx], self.bit_widths[idx],
                 self.word_offsets[idx], self.packed[idx], self.counts[idx])
+
+
+def slab_plan(plan: Tuple[np.ndarray, ...]) -> Tuple[np.ndarray, ...]:
+    """An unpack plan (:meth:`PackedPages.unpack_plan`) laid out page by
+    page, for the Pallas decode kernel.
+
+    ``pos``, ``mind`` and ``packed`` become ``[n_pages, page_size //
+    SLAB_LANES, SLAB_LANES]``: one page is one slab that a DMA moves
+    whole, and lane ``j`` of row ``t`` is position ``SLAB_LANES * t +
+    j``.  ``pos`` and ``mind`` gain position 0, the page's first id as a
+    zero-width delta (``mind`` = ``first``), so a row-major inclusive
+    scan of the decoded deltas is the page's ids.  ``first`` is kept as
+    it is.
+    """
+    first, pos, mind, packed = plan
+    n, ps = packed.shape
+    shape = (n, ps // SLAB_LANES, SLAB_LANES)
+    pos = np.concatenate([np.zeros((n, 1), pos.dtype), pos], axis=1)
+    mind = np.concatenate([first.astype(mind.dtype), mind], axis=1)
+    return first, pos.reshape(shape), mind.reshape(shape), \
+        packed.reshape(shape)
+
+
+def engine_plan(plan: Tuple[np.ndarray, ...], engine: str,
+                page_size: int) -> Tuple[np.ndarray, ...]:
+    """The layout an engine decodes a resident plan from: the Pallas
+    engine's is the slab plan wherever a page makes whole slabs; every
+    other (the jnp engine's, and small pages) keeps the row plan."""
+    if engine == "pallas" and page_size % SLAB_TILE == 0:
+        return slab_plan(plan)
+    return plan
 
 
 @dataclasses.dataclass

@@ -48,7 +48,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .encoding import DeltaColumn, PackedPages, build_packed, hull_intersects
+from .encoding import (DeltaColumn, PackedPages, build_packed, engine_plan,
+                       hull_intersects)
 
 #: sharded-retrieval default: ``REPRO_PARTITIONS=N`` partitions every
 #: column the retrieval plane packs (0 / unset keeps the monolithic
@@ -247,6 +248,10 @@ class PartitionedColumn:
             out.append(stack)
         return tuple(out)
 
+    def _engine_plan_host(self, engine: str) -> Tuple[np.ndarray, ...]:
+        """The stacked plan in the engine's layout (``engine_plan``)."""
+        return engine_plan(self.stacked_plan_host(), engine, self.page_size)
+
     def device_plan(self, engine: str) -> Tuple:
         """Engine-keyed sharded device plan: ``(mesh, arrays, pmax)``.
 
@@ -265,7 +270,7 @@ class PartitionedColumn:
             arrays = tuple(
                 jax.device_put(a, NamedSharding(
                     mesh, PartitionSpec("part", *(None,) * (a.ndim - 1))))
-                for a in self.stacked_plan_host())
+                for a in self._engine_plan_host(engine))
             ppd = self.n_parts // len(devs)
             for p in self.parts:
                 p.device = devs[p.index // ppd]
@@ -298,7 +303,7 @@ class PartitionedColumn:
             else:
                 import jax.numpy as jnp
                 arrays = tuple(jnp.asarray(a)
-                               for a in self.stacked_plan_host())
+                               for a in self._engine_plan_host(engine))
                 plan = (arrays, self.pmax)
                 self.device_transfers += 1
             if self.parts and self.parts[0].device is None:

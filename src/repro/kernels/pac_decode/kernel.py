@@ -8,11 +8,18 @@ requested ids a word tile at a time instead of serial bit appends.
 
 Power-of-two miniblock bit widths guarantee no packed value straddles a
 32-bit word, so the unpack is a single gather + variable shift per lane --
-the same alignment argument the paper uses for its SIMD path.  Mosaic
-lowers no lane gather, so the gathers (page rows, per-delta words,
-requested ids) and the elementwise unpack run in XLA inside the same jit;
-the kernels do the parts that are lane-parallel:
+the same alignment argument the paper uses for its SIMD path.  The
+kernels:
 
+  * ``_decode_slabs_pallas`` -- the resident decode.  Each staged page
+                              index is scalar-prefetched and drives the
+                              DMA of that page's slab of the plan
+                              (``repro.core.encoding.slab_plan``) into
+                              VMEM; there each delta's packed word is a
+                              lane ``dynamic_gather`` within 128-lane
+                              rows plus a select over the word rows,
+                              then shift, mask, ``mind`` and the page's
+                              row-major scan (``pltpu.roll``).
   * ``_scan_rows_pallas``  -- first id + inclusive row scan of the deltas
                               (Hillis-Steele over ``pltpu.roll``), gridded
                               over blocks of page rows.
@@ -23,6 +30,12 @@ the kernels do the parts that are lane-parallel:
                               hits with a one-hot matmul on the MXU;
                               exact under duplicates, optionally ANDed
                               with a predicate plane.
+
+XLA, inside the same jit, gathers the requested ids for the bitmap tail
+and, where a plan is in the row layout (the jnp engine's, and a page too
+small to make whole (8, 128) slabs), gathers page rows and per-delta
+words and unpacks them for ``_scan_rows_pallas``; so do the per-dispatch
+packed-page entries below.
 
 Entries: ``gather_decode_pallas`` / ``fused_gather_decode_bitmap_batch``
 (device-resident unpack plan, the served path), ``delta_decode_pallas`` /
@@ -42,7 +55,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.encoding import (DEFAULT_PAGE_SIZE, MINIBLOCK, POS_BW_MASK,
-                                 POS_SHIFT_SHIFT, POS_WIDX_SHIFT)
+                                 POS_SHIFT_SHIFT, POS_WIDX_SHIFT, SLAB_LANES)
 from repro.kernels._pad import (next_multiple, note_trace, pallas_interpret,
                                 refuse_off_cpu)
 
@@ -173,8 +186,8 @@ def _page_deltas(min_deltas, bit_widths, word_offsets, packed, counts,
                  page_size):
     """All pages' packed miniblocks -> per-position deltas, one shot.
 
-    Every step is an elementwise op or a row-gather (XLA work -- Mosaic
-    has no lane gather).  Column 0 is a
+    Every step is an elementwise op or a row-gather, run in XLA.
+    Column 0 is a
     zero delta and deltas past ``count - 1`` are zeroed, so an inclusive
     row scan plus ``first`` yields ``ids[n_pages, page_size]`` with
     positions >= count holding the running last id.
@@ -239,9 +252,9 @@ def _gather_rows(idx, *arrays):
     """On-device row gather of resident column arrays by page index.
 
     ``idx`` is int32[p_pad] (pow2 size-classed, clip-padded with 0); the
-    arrays stay on device across dispatches, so this gather is the only
-    per-dispatch data movement the packed column requires -- the host
-    ships the index vector, never page bytes.
+    arrays stay on device across dispatches, so the host ships the index
+    vector, never page bytes.  A row plan's decode starts here; a slab
+    plan's kernel DMAs the pages by index itself.
     """
     return tuple(jnp.take(a, idx, axis=0, mode="clip") for a in arrays)
 
@@ -274,9 +287,10 @@ def _plan_deltas(pos, mind, packed):
     effective bit width into one int32 lane, so this is one gather + a
     few elementwise ops.  Column 0 is a zero delta (the page's first id
     is ``first``), so an inclusive row scan yields the ids directly.
-    The word gather is a per-row lane gather, which Mosaic does not
-    lower: it runs in XLA, fused with the page-row gather, and only the
-    scan is a kernel.
+    This is the jnp engine's decode and the Pallas engine's for a row
+    plan: the word gather runs in XLA, fused with the page-row gather,
+    and only the scan is a kernel.  A slab plan decodes the same deltas
+    in VMEM instead (:func:`_decode_slab`).
     """
     word_idx = pos >> POS_WIDX_SHIFT
     shift = ((pos >> POS_SHIFT_SHIFT) & 31).astype(jnp.uint32)
@@ -498,10 +512,119 @@ def _bitmap_tail_pallas(ids, gidx, gcount, n_words, interpret,
     return jax.lax.bitcast_convert_type(out.reshape(n_words), jnp.uint32)
 
 
-def _decode_gathered(g, interpret):
-    """Gathered plan rows -> decoded ``int32[n, page_size]`` page matrix
-    (XLA word gather + unpack, Mosaic row scan)."""
-    first, pos, mind, packed = g
+# --------------------------------------------------------------------------
+# resident decode: each staged page DMAed by its index, decoded in VMEM
+# --------------------------------------------------------------------------
+
+#: staged pages per grid step, each brought in by a DMA of its own
+PAGES_PER_STEP = 8
+
+
+def _decode_slab(pos, mind, packed):
+    """One page's slab (``[page_size // 128, 128]``, lane ``j`` of row
+    ``t`` is position ``128 t + j``) -> its ids, in VMEM.
+
+    Each lane's packed word is a lane ``dynamic_gather`` from every word
+    row, kept where the lane's word index falls in that row.  Lane 0
+    carries the page's first id as its delta (width 0), so the row-major
+    inclusive scan -- a Hillis-Steele scan over the lanes of each row
+    (``pltpu.roll``), then one over the rows' totals -- is the ids.
+    """
+    word = _slab_words(pos >> POS_WIDX_SHIFT, packed)
+    shift = ((pos >> POS_SHIFT_SHIFT) & 31).astype(jnp.uint32)
+    bw = (pos & POS_BW_MASK).astype(jnp.uint32)
+    mask = jnp.where(bw >= 32, jnp.uint32(0xFFFFFFFF),
+                     (jnp.uint32(1) << bw) - 1)
+    x = ((word >> shift) & mask).astype(jnp.int32) + mind
+    lanes = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    s = 1
+    while s < x.shape[1]:
+        x = x + jnp.where(lanes >= s, pltpu.roll(x, s, 1), 0)
+        s *= 2
+    total = jnp.broadcast_to(x[:, -1:], x.shape)
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    carry = total
+    s = 1
+    while s < x.shape[0]:
+        carry = carry + jnp.where(rows >= s, pltpu.roll(carry, s, 0), 0)
+        s *= 2
+    return x + carry - total
+
+
+def _slab_words(widx, packed):
+    """The packed word at each lane's word index ``widx``: a lane gather
+    from every word row, kept where ``widx`` falls in that row."""
+    lane = widx & (SLAB_LANES - 1)
+    row_of = widx >> (SLAB_LANES.bit_length() - 1)
+    word = jnp.zeros(widx.shape, packed.dtype)
+    for r in range(widx.shape[0]):
+        row = jnp.broadcast_to(packed[r:r + 1, :], widx.shape)
+        got = jnp.take_along_axis(row, lane, axis=1,
+                                  mode="promise_in_bounds")
+        word = jnp.where(row_of == r, got, word)
+    return word
+
+
+def _slab_kernel(idx_ref, *refs):
+    """Decode the step's pages: refs are ``PAGES_PER_STEP``-or-fewer
+    page blocks of ``pos``, then of ``mind``, then of ``packed``, then
+    the step's output block."""
+    *ins, out_ref = refs
+    b = len(ins) // 3
+    for k in range(b):
+        out_ref[k] = _decode_slab(ins[k][0], ins[b + k][0],
+                                  ins[2 * b + k][0])
+
+
+def _decode_slabs_pallas(idx, pos, mind, packed, interpret):
+    """Slab plan (``repro.core.encoding.slab_plan``) + page indices ->
+    ``int32[len(idx), page_size]`` ids, one Mosaic kernel.
+
+    ``idx`` is scalar-prefetched; each page's block index map reads its
+    entry, so the pipeline DMAs exactly the staged pages from HBM, a few
+    per grid step, and nothing else of the plan.  Indices are clipped to
+    the plan, as the row path's gather clips them."""
+    idx = jnp.clip(idx, 0, pos.shape[0] - 1)
+    p = idx.shape[0]
+    _, rows, lanes = pos.shape
+    b = next(k for k in (PAGES_PER_STEP, 4, 2, 1) if p % k == 0)
+
+    def page_spec(k):
+        return pl.BlockSpec((1, rows, lanes),
+                            lambda i, ix: (ix[i * b + k], 0, 0))
+
+    specs = [page_spec(k) for k in range(b)]
+    ids = pl.pallas_call(
+        _slab_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(p // b,),
+            in_specs=specs * 3,
+            out_specs=pl.BlockSpec((b, rows, lanes),
+                                   lambda i, ix: (i, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((p, rows, lanes), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=pallas_interpret(interpret),
+    )(idx, *[pos] * b, *[mind] * b, *[packed] * b)
+    return ids.reshape(p, rows * lanes)
+
+
+def decodes_in_kernel(plan) -> bool:
+    """Whether a resident plan is decoded by the slab kernel: a plan in
+    the slab layout (``pos`` page-leading 3-D) is; a row plan -- the
+    jnp engine's, or a page size that makes no whole slab -- takes the
+    XLA gathers and the row scan."""
+    return plan[1].ndim == 3
+
+
+def _decode_resident(idx, plan, interpret):
+    """Resident plan + page indices -> ``int32[len(idx), page_size]``."""
+    if decodes_in_kernel(plan):
+        with jax.named_scope("gather_words"):
+            return _decode_slabs_pallas(idx, *plan[1:], interpret)
+    with jax.named_scope("gather_rows"):
+        first, pos, mind, packed = _gather_rows(idx, *plan)
     return _scan_rows_pallas(first, _plan_deltas(pos, mind, packed),
                              interpret)
 
@@ -514,14 +637,14 @@ def gather_decode_pallas(first, pos, mind, packed, idx,
 
     Inputs are the column's device unpack plan
     (``PackedPages.device_plan`` -- whole-column arrays, constant shapes
-    across dispatches); ``idx`` selects the pages.  Returns
+    across dispatches; a slab plan decodes in one kernel, a row plan
+    through the XLA gathers); ``idx`` selects the pages.  Returns
     ``int32[p_pad, page_size]`` in ``idx`` order (clip-padded rows decode
     page 0 and are sliced off by the caller).
     """
     note_trace("gather_decode")
-    del page_size  # implied by the plan's per-delta shape
-    return _decode_gathered(_gather_rows(idx, first, pos, mind, packed),
-                            interpret)
+    del page_size  # implied by the plan's shape
+    return _decode_resident(idx, (first, pos, mind, packed), interpret)
 
 
 def _split_staged(staged, p_pad):
@@ -541,10 +664,8 @@ def fused_gather_bitmap(first, pos, mind, packed, staged, p_pad, n_words,
     idx, gidx, gcount = _split_staged(staged, p_pad)
     # named scopes label each phase's device ops in the profiler trace
     # under a name that survives XLA's renumbering of its fusions
-    with jax.named_scope("gather_rows"):
-        g = _gather_rows(idx, first, pos, mind, packed)
     with jax.named_scope("decode"):
-        ids = _decode_gathered(g, interpret)
+        ids = _decode_resident(idx, (first, pos, mind, packed), interpret)
     with jax.named_scope("bitmap_tail"):
         words = _bitmap_tail_pallas(ids, gidx, gcount[0, 0], n_words,
                                     interpret, fwords=fwords)

@@ -182,6 +182,13 @@ def _count_dispatch(rows: int, pages: int, page_size: int) -> None:
     obs.count("retrieve.lanes_decoded", pages * (page_size - 1))
 
 
+def _count_kernel_pages(plan, pages: int) -> None:
+    """Pages a resident dispatch decodes in the slab kernel (none where
+    its plan takes the XLA gathers)."""
+    if K.decodes_in_kernel(plan):
+        obs.count("decode.kernel_pages", pages)
+
+
 def _assemble(words: np.ndarray, target_page_size: int) -> PAC:
     with obs.span(obs.ASSEMBLE):
         return PAC.from_dense_bitmap(words, target_page_size)
@@ -282,6 +289,7 @@ def _sharded_decode_matrix(col: DeltaColumn, parts, pages: Sequence[int],
         fn = K.gather_decode_pallas if engine == "pallas" \
             else R.gather_decode_ref
         ids = fn(*arrays, jnp.asarray(idx), page_size=ps)
+        _count_kernel_pages(arrays, len(idx))
         return np.asarray(ids[:len(pages_arr)], np.int64)
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
@@ -298,6 +306,7 @@ def _sharded_decode_matrix(col: DeltaColumn, parts, pages: Sequence[int],
                           NamedSharding(mesh, PartitionSpec("part", None)))
     fn = sharded_decode_entry(mesh, engine, ps, p_pad)
     mat = np.asarray(fn(*plan, jidx), np.int64)  # [g, p_pad, ps]
+    _count_kernel_pages(plan, g * p_pad)
     # row of page i = its appearance order within its device's bucket --
     # the same masks that filled idxmat, so correct for any page order
     within = np.empty(len(pages_arr), np.int64)
@@ -350,6 +359,7 @@ def _decode_page_matrix(col: DeltaColumn, pages: Sequence[int],
         else:
             ids = R.gather_decode_ref(*plan, jnp.asarray(idx),
                                       page_size=ps)
+        _count_kernel_pages(plan, len(idx))
         counts = packed.counts[np.asarray(pages, np.int64), 0]
     else:
         args = pack_page_list(col, pages)
@@ -649,6 +659,7 @@ def _retrieve_pac_batch_sharded(col: DeltaColumn, parts, los, his, pages,
                          page_size=ps, n_words=n_words, p_pad=p_pad,
                          want_ids=want_ids)
         _count_dispatch(total, p_pad, ps)
+        _count_kernel_pages(arrays, p_pad)
         if want_ids:
             words, ids = out
             mat = _pull(ids).astype(np.int64)
@@ -694,6 +705,7 @@ def _retrieve_pac_batch_sharded(col: DeltaColumn, parts, los, his, pages,
         out = fn(*plan, jstaged, *fargs)
     # each shard decodes p_pad pages
     _count_dispatch(len(gidx), g * p_pad, ps)
+    _count_kernel_pages(plan, g * p_pad)
     if want_ids:
         planes, ids = out
         mat = _pull(ids).astype(np.int64)  # [g, p_pad, ps]
@@ -832,6 +844,7 @@ def _retrieve_pac_batch_fused(col: DeltaColumn, los, his,
                          page_size=ps, n_words=n_words, p_pad=p_pad,
                          want_ids=want_ids)
         _count_dispatch(total, p_pad, ps)
+        _count_kernel_pages(plan, p_pad)
         if want_ids:
             words, ids = out
             mat = _pull(ids).astype(np.int64)

@@ -10,8 +10,9 @@ session an annotation is inactive and costs well under a microsecond.
 hard-codes them.
 
 **Counters** are plain Python ints, always on: requests, rows, decoded
-lanes and bytes moved across the host-device boundary, counted where the
-work happens.  ``counters`` returns a copy; a reader takes the change
+lanes, pages decoded in the resident slab kernel
+(``decode.kernel_pages``) and bytes moved across the host-device
+boundary, counted where the work happens.  ``counters`` returns a copy; a reader takes the change
 between two copies.  The kernel layer's trace counter
 (:func:`repro.kernels._pad.note_trace`) records here too, under
 ``traces/<entry>``.

@@ -158,6 +158,37 @@ def test_partitioned_retrieval_opens_the_same_spans(batch, engine, spans):
     np.testing.assert_array_equal(ids, _expected_ids(adj, batch))
 
 
+@pytest.mark.parametrize("engine,page,partitions,kernel", [
+    ("pallas", 1024, None, True),     # slab plan: the Mosaic kernel
+    ("pallas", 1024, 2, True),        # partition plane, single-shard tail
+    ("pallas", PAGE, None, False),    # no whole slab: the XLA gathers
+    ("jax", 1024, None, False),       # the jnp engine's row plan
+])
+def test_kernel_pages_count_each_slab_dispatch(batch, engine, page,
+                                               partitions, kernel):
+    """``decode.kernel_pages`` advances by each dispatch's ``p_pad``
+    where the slab kernel decodes, and stays put on the XLA path."""
+    src, dst = powerlaw_graph(N, 6, seed=13)
+    adj = build_adjacency(src, dst, N, N, BY_SRC, ENC_GRAPHAR,
+                          page_size=page)
+    col = adj.table["<dst>"].encoded
+    cap = len(col.pages)    # the page class's cap: the plan's rows
+    if partitions:
+        cap = partition_column(col, partitions).stack_rows
+    want = retrieve_neighbors_batch(adj, batch, TPS, engine="numpy").to_ids()
+    for sub in (batch[:16], batch):
+        los, his = adj.edge_ranges_batch(np.asarray(sub, np.int64))
+        n_pages = len(pdo.page_set_for_ranges(los, his, page)[0])
+        before = obs.counters().get("decode.kernel_pages", 0)
+        ids = retrieve_neighbors_batch(adj, sub, TPS, engine=engine,
+                                       fused=True, resident=True).to_ids()
+        moved = obs.counters().get("decode.kernel_pages", 0) - before
+        assert moved == (pdo._page_class(n_pages, cap) if kernel else 0)
+        np.testing.assert_array_equal(ids, retrieve_neighbors_batch(
+            adj, sub, TPS, engine="numpy").to_ids())
+    np.testing.assert_array_equal(ids, want)
+
+
 def test_counters_are_copies_and_reset_by_prefix():
     obs.count("test_obs.a")
     obs.count("test_obs.a", 4)

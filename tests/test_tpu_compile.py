@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.encoding import SLAB_LANES
 from repro.core.labels import L, compile_cond
 from repro.kernels import _pad
 from repro.kernels.bitmap_select import kernel as BK
@@ -68,9 +69,11 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-def _plan(sds):
-    return (sds((PLAN_ROWS, 1), I32), sds((PLAN_ROWS, PAGE - 1), I32),
-            sds((PLAN_ROWS, PAGE - 1), I32), sds((PLAN_ROWS, PAGE), U32))
+def _plan(sds, rows=PLAN_ROWS):
+    """The Pallas engine's resident plan: the slab layout."""
+    slab = (rows, PAGE // SLAB_LANES, SLAB_LANES)
+    return (sds((rows, 1), I32), sds(slab, I32), sds(slab, I32),
+            sds(slab, U32))
 
 
 def _pack_args(sds, n):
@@ -102,6 +105,12 @@ COMPILES = {
             *_plan(s), s((P_PAD + T_PAD + 1,), I32), s((N_WORDS,), U32),
             s((N_WORDS,), U32), page_size=PAGE, n_words=N_WORDS,
             p_pad=P_PAD, want_ids=False, interpret=False),
+    # the slab kernel alone, at the page classes of a LiveJournal request
+    **{f"_decode_slabs_pallas[p_pad={p}]": (
+        lambda s, p=p: jax.jit(K._decode_slabs_pallas,
+                               static_argnames="interpret").lower(
+            s((p,), I32), *_plan(s)[1:], interpret=False))
+       for p in (1024, 2048)},
     "delta_decode_pallas": lambda s: K.delta_decode_pallas.lower(
         *_pack_args(s, P_PAD), page_size=PAGE, interpret=False),
     "fused_decode_bitmap_batch": lambda s: K.fused_decode_bitmap_batch.lower(
@@ -145,8 +154,7 @@ def test_sharded_fused_entry_compiles_for_v5e_2x2(topo, no_compile_cache,
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, P("part")))
 
-    plan = (sds((g * rows, 1), I32), sds((g * rows, PAGE - 1), I32),
-            sds((g * rows, PAGE - 1), I32), sds((g * rows, PAGE), U32))
+    plan = _plan(sds, g * rows)
     fn = sharded_fused_entry(mesh, "pallas", PAGE, N_WORDS, P_PAD, False,
                              False)
     compiled = fn.lower(*plan, sds((g, P_PAD + T_PAD + 1), I32)).compile()
