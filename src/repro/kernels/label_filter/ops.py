@@ -20,6 +20,7 @@ from typing import Dict, Tuple, Union
 import numpy as np
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.labels import (Cond, CondProgram, Intervals,
                                bitmap_to_intervals, charge_label_metadata,
                                compile_cond, interval_hull,
@@ -102,6 +103,7 @@ class FilterPlan:
         arrs = self._device.get(engine)
         if arrs is None:
             arrs = (jnp.asarray(self.pos), jnp.asarray(self.meta))
+            obs.count("transfer.h2d_bytes", self.pos.nbytes + self.meta.nbytes)
             self._device[engine] = arrs
         return arrs
 
@@ -111,11 +113,13 @@ class FilterPlan:
         Evaluated once per (engine, n_words) by the cond kernel over the
         device-mirrored run arrays (tile-padded, then sliced); lanes past
         ``count`` are zero, matching the per-dispatch evaluation of the
-        non-resident fused kernel bit for bit.
+        non-resident fused kernel bit for bit.  Each evaluation counts
+        one ``filter.planes``.
         """
         key = (engine, n_words)
         words = self._device_bitmaps.get(key)
         if words is None:
+            obs.count("filter.planes")
             pos, meta = self.device(engine)
             padded = next_multiple(max(n_words, 1), K.WORD_TILE)
             fn = K.cond_bitmap_pallas if engine == "pallas" \
@@ -173,6 +177,7 @@ def label_filter_bitmap(vt: VertexTable, cond: Union[Cond, CondProgram],
         raise ValueError(f"unknown engine {engine!r}; want one of {ENGINES}")
     plan = make_plan(vt, program)
     n_words = next_multiple(plan.n_words or 1, K.WORD_TILE)
+    obs.count("filter.planes")
     if engine == "pallas":
         words = K.cond_bitmap_pallas(jnp.asarray(plan.pos),
                                      jnp.asarray(plan.meta),

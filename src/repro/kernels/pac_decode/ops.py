@@ -189,6 +189,15 @@ def _count_kernel_pages(plan, pages: int) -> None:
         obs.count("decode.kernel_pages", pages)
 
 
+def _qual_range(label_filter):
+    """The filter's qualifying hull under the label plane's span (None
+    without a filter)."""
+    if label_filter is None:
+        return None
+    with obs.span(obs.FILTER):
+        return label_filter.qual_range()
+
+
 def _assemble(words: np.ndarray, target_page_size: int) -> PAC:
     with obs.span(obs.ASSEMBLE):
         return PAC.from_dense_bitmap(words, target_page_size)
@@ -586,7 +595,7 @@ def _retrieve_pac_batch_sharded(col: DeltaColumn, parts, los, his, pages,
     """
     ps = col.page_size
     with obs.span(obs.PLAN):
-        qual = filter_plan.qual_range() if filter_plan is not None else None
+        qual = _qual_range(filter_plan)
         owner, mask = parts.prune(pages, qual)
         if mask is not None:
             pages = pages[mask]
@@ -651,7 +660,8 @@ def _retrieve_pac_batch_sharded(col: DeltaColumn, parts, los, his, pages,
             else:
                 from repro.kernels.label_filter import kernel as LK
                 from repro.kernels.label_filter import ref as LR
-                fwords = filter_plan.device_bitmap(engine, n_words)
+                with obs.span(obs.FILTER):
+                    fwords = filter_plan.device_bitmap(engine, n_words)
                 fn = (LK.fused_gather_decode_filter_bitmap_batch
                       if engine == "pallas"
                       else LR.fused_gather_filter_batch_ref)
@@ -698,7 +708,9 @@ def _retrieve_pac_batch_sharded(col: DeltaColumn, parts, los, his, pages,
     jstaged = _put(staged, NamedSharding(mesh, PartitionSpec("part", None)))
     fargs = ()
     if filter_plan is not None:
-        fargs = (filter_plan.device_bitmap_sharded(engine, n_words, mesh),)
+        with obs.span(obs.FILTER):
+            fargs = (filter_plan.device_bitmap_sharded(engine, n_words,
+                                                       mesh),)
     fn = sharded_fused_entry(mesh, engine, ps, n_words, p_pad, want_ids,
                              filter_plan is not None)
     with obs.span(obs.LAUNCH):
@@ -783,7 +795,7 @@ def _retrieve_pac_batch_fused(col: DeltaColumn, los, his,
         # never gathered onto the device, never decoded, never charged
         # (the sharded path above applies the same sieve after its
         # partition-level prune)
-        qual = filter_plan.qual_range() if filter_plan is not None else None
+        qual = _qual_range(filter_plan)
         pages, pmask = prune_page_list(col, pages, qual)
         if pages.size == 0:  # every page statistics-pruned
             return PAC(target_page_size)
@@ -836,7 +848,8 @@ def _retrieve_pac_batch_fused(col: DeltaColumn, los, his,
             else:
                 from repro.kernels.label_filter import kernel as LK
                 from repro.kernels.label_filter import ref as LR
-                fwords = filter_plan.device_bitmap(engine, n_words)
+                with obs.span(obs.FILTER):
+                    fwords = filter_plan.device_bitmap(engine, n_words)
                 fn = (LK.fused_gather_decode_filter_bitmap_batch
                       if engine == "pallas"
                       else LR.fused_gather_filter_batch_ref)
@@ -881,7 +894,8 @@ def _retrieve_pac_batch_fused(col: DeltaColumn, los, his,
     jargs = [_put(a) for a in args] \
         + [_put(cached), _put(gidx), _put(np.full((1, 1), total, np.int32))]
     if filter_plan is not None:
-        fargs = [_put(filter_plan.pos), _put(filter_plan.meta)]
+        with obs.span(obs.FILTER):
+            fargs = [_put(filter_plan.pos), _put(filter_plan.meta)]
     with obs.span(obs.LAUNCH):
         if filter_plan is None:
             if engine == "pallas":
@@ -895,6 +909,7 @@ def _retrieve_pac_batch_fused(col: DeltaColumn, los, his,
             from repro.kernels.label_filter import ref as LR
             fn = (LK.fused_decode_filter_bitmap_batch if engine == "pallas"
                   else LR.fused_filter_batch_ref)
+            obs.count("filter.planes")  # evaluated inside the dispatch
             words, ids = fn(*jargs, *fargs, page_size=ps, n_words=n_words,
                             ops=filter_plan.program.ops)
     # the miss pages, padded to m_pad, are the only ones decoded: cache
@@ -945,6 +960,8 @@ def retrieve_pac_batch(col: DeltaColumn, los, his, target_page_size: int,
     """
     los = np.asarray(los, np.int64)
     his = np.asarray(his, np.int64)
+    if label_filter is not None:
+        obs.count("filter.requests")
     if fused is None:
         fused = (engine != "numpy" and num_targets is not None
                  and target_page_size % 32 == 0
@@ -954,7 +971,8 @@ def retrieve_pac_batch(col: DeltaColumn, los, his, target_page_size: int,
             raise ValueError("fused=True requires num_targets")
         plan = None
         if label_filter is not None:
-            plan = label_filter.plan()
+            with obs.span(obs.FILTER):
+                plan = label_filter.plan()
             if plan.count != int(num_targets):
                 raise ValueError(
                     f"filter covers {plan.count} vertices but the target "
@@ -967,12 +985,14 @@ def retrieve_pac_batch(col: DeltaColumn, los, his, target_page_size: int,
         # (pruned pages hold no qualifying ids, and the intersect below
         # removes exactly those ids on the unpruned path), so meters
         # agree with the fused dispatches bit for bit
-        qual = label_filter.qual_range() if label_filter is not None else None
+        qual = _qual_range(label_filter)
         ids = decode_row_ranges(col, los, his, meter, engine, qual=qual)
         pac = PAC.from_ids(np.unique(ids), target_page_size) if ids.size \
             else PAC(target_page_size)
         if label_filter is not None:
-            pac = pac.intersect(label_filter.pac(target_page_size))
+            with obs.span(obs.FILTER):
+                fpac = label_filter.pac(target_page_size)
+            pac = pac.intersect(fpac)
     if delta_ids is not None and len(delta_ids):
         pac = pac.union(PAC.from_ids(np.asarray(delta_ids, np.int64),
                                      target_page_size))

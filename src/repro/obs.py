@@ -6,16 +6,19 @@ session each span lands in the profiler's host plane, on the same clock
 as the device's XLA ops, so a reduction of the trace can put every
 device-idle gap down to the innermost span the host was in.  Outside a
 session an annotation is inactive and costs well under a microsecond.
-:data:`SPANS` lists every name the program opens, so a reader never
-hard-codes them.
+:data:`SPANS` lists the names every retrieval opens and
+:data:`ALL_SPANS` every name the program opens (the label plane's
+``graphar.filter`` besides), so a reader never hard-codes them.
 
 **Counters** are plain Python ints, always on: requests, rows, decoded
 lanes, pages decoded in the resident slab kernel
-(``decode.kernel_pages``) and bytes moved across the host-device
-boundary, counted where the work happens.  ``counters`` returns a copy; a reader takes the change
-between two copies.  The kernel layer's trace counter
-(:func:`repro.kernels._pad.note_trace`) records here too, under
-``traces/<entry>``.
+(``decode.kernel_pages``), bytes moved across the host-device
+boundary, and the label plane's retrievals that carry a filter
+(``filter.requests``) and predicate planes evaluated on the device
+(``filter.planes``), counted where the work happens.  ``counters``
+returns a copy; a reader takes the change between two copies.  The
+kernel layer's trace counter (:func:`repro.kernels._pad.note_trace`)
+records here too, under ``traces/<entry>``.
 """
 from __future__ import annotations
 
@@ -38,14 +41,21 @@ PULL = "graphar.pull"
 ASSEMBLE = "graphar.assemble"
 #: ids from a PAC
 TO_IDS = "graphar.to_ids"
+#: the label plane's host work for a retrieval that carries a filter:
+#: the filter's kernel inputs, its qualifying hull, the put of its run
+#: arrays and the enqueue of its predicate plane
+FILTER = "graphar.filter"
 
+#: the spans every batched retrieval opens
 SPANS = (RETRIEVE, EDGE_RANGES, PLAN, UPLOAD, LAUNCH, PULL, ASSEMBLE, TO_IDS)
+#: every span the program opens
+ALL_SPANS = SPANS + (FILTER,)
 
 _COUNTERS: Dict[str, int] = {}
 
 
 def span(name: str):
-    """A host span named ``name`` (one of :data:`SPANS`), as a context
+    """A host span named ``name`` (one of :data:`ALL_SPANS`), as a context
     manager: a :class:`jax.profiler.TraceAnnotation`.  JAX is imported
     here, on first use, so the numpy storage plane stays free of it."""
     from jax.profiler import TraceAnnotation

@@ -2,7 +2,9 @@
 
 A fused retrieval opens the span of each layer boundary in a fixed
 nesting, advances each counter by exactly what its dispatch moved, and
-returns the same ids it returns untraced.  The kernel layer's trace
+returns the same ids it returns untraced.  A filtered one opens the
+label plane's span where it works, and counts each predicate plane it
+has the device evaluate.  The kernel layer's trace
 counter keeps its functions and values on top of the registry.
 """
 import contextlib
@@ -16,9 +18,11 @@ import pytest
 
 from _engines import engines
 from repro import obs
-from repro.core import (BY_SRC, ENC_GRAPHAR, build_adjacency,
+from repro.core import (BY_SRC, ENC_GRAPHAR, L, LabelFilter, build_adjacency,
                         partition_column, retrieve_neighbors_batch)
-from repro.data.synthetic import powerlaw_graph
+from repro.core.schema import VertexTypeSchema
+from repro.core.vertex import VertexTable
+from repro.data.synthetic import clustered_labels, powerlaw_graph
 from repro.kernels import _pad
 from repro.kernels.pac_decode import ops as pdo
 
@@ -33,6 +37,14 @@ RESIDENT = [(obs.RETRIEVE, [(obs.EDGE_RANGES, []), (obs.PLAN, []),
                             (obs.PLAN, []), (obs.UPLOAD, []),
                             (obs.LAUNCH, []), (obs.PULL, []),
                             (obs.ASSEMBLE, [])]),
+            (obs.TO_IDS, [])]
+#: the same, with a fresh label filter: the filter's kernel inputs, its
+#: qualifying hull in the page planning, its predicate plane at launch
+FILTERED = [(obs.RETRIEVE, [(obs.EDGE_RANGES, []), (obs.FILTER, []),
+                            (obs.PLAN, []), (obs.PLAN, [(obs.FILTER, [])]),
+                            (obs.UPLOAD, []),
+                            (obs.LAUNCH, [(obs.FILTER, [])]),
+                            (obs.PULL, []), (obs.ASSEMBLE, [])]),
             (obs.TO_IDS, [])]
 
 
@@ -50,6 +62,19 @@ def adj():
 @pytest.fixture(scope="module")
 def batch():
     return np.random.default_rng(17).choice(N, 64, replace=False)
+
+
+@pytest.fixture(scope="module")
+def labels():
+    return clustered_labels(N, ["A", "B"], density=0.3, run_scale=64,
+                            seed=5)
+
+
+@pytest.fixture(scope="module")
+def vt(labels):
+    return VertexTable.build(VertexTypeSchema("v", [], labels=list(labels),
+                                              page_size=PAGE),
+                             {}, labels, num_vertices=N)
 
 
 @pytest.fixture
@@ -102,6 +127,75 @@ def test_resident_retrieval_opens_each_span_in_its_nesting(adj, batch,
     assert {name for name, _ in RESIDENT} | \
         {name for _, kids in RESIDENT for name, _ in kids} == set(obs.SPANS)
     np.testing.assert_array_equal(ids, _expected_ids(adj, batch))
+
+
+def _filter_counts(adj, batch, engine, filt, resident=True):
+    """Ids of one filtered retrieval, and how far it moved the label
+    plane's counters."""
+    before = obs.counters()
+    ids = retrieve_neighbors_batch(adj, batch, TPS, engine=engine,
+                                   fused=True, resident=resident,
+                                   filter=filt).to_ids()
+    after = obs.counters()
+    return ids, {k: after.get(k, 0) - before.get(k, 0)
+                 for k in ("filter.requests", "filter.planes")}
+
+
+def _filtered_ids(adj, batch, labels, keep):
+    """The numpy oracle's ids where ``keep`` of the label columns
+    holds."""
+    want = _expected_ids(adj, batch)
+    return want[keep(labels)[want]]
+
+
+#: label conditions, each with its numpy twin over the label columns
+CONDS = [(L("A"), lambda c: c["A"]),
+         ((L("A") & ~L("B")) | L("B"), lambda c: (c["A"] & ~c["B"]) | c["B"])]
+COND_IDS = [repr(cond) for cond, _ in CONDS]
+
+
+@pytest.mark.parametrize("engine", engines(kernel_only=True))
+@pytest.mark.parametrize("cond,keep", CONDS, ids=COND_IDS)
+def test_filtered_retrieval_opens_the_filter_span_in_its_nesting(
+        adj, batch, vt, labels, engine, cond, keep, spans):
+    ids, _ = _filter_counts(adj, batch, engine, LabelFilter(vt, cond))
+    assert spans == FILTERED
+    assert {name for name, _ in FILTERED} | \
+        {name for _, kids in FILTERED for name, _ in kids} == \
+        set(obs.ALL_SPANS)
+    np.testing.assert_array_equal(ids,
+                                  _filtered_ids(adj, batch, labels, keep))
+
+
+@pytest.mark.parametrize("engine", engines(kernel_only=True))
+@pytest.mark.parametrize("cond,keep", CONDS, ids=COND_IDS)
+def test_filtered_retrieval_counts_each_plane_it_builds(adj, batch, vt,
+                                                        labels, engine,
+                                                        cond, keep):
+    """A fresh filter builds its plane on the device; a reused one
+    ANDs the plane it already has."""
+    want = _filtered_ids(adj, batch, labels, keep)
+    filt = LabelFilter(vt, cond)
+    for planes in (1, 0):
+        ids, moved = _filter_counts(adj, batch, engine, filt)
+        assert moved == {"filter.requests": 1, "filter.planes": planes}
+        np.testing.assert_array_equal(ids, want)
+    _, moved = _filter_counts(adj, batch, engine, LabelFilter(vt, cond))
+    assert moved == {"filter.requests": 1, "filter.planes": 1}
+
+
+@pytest.mark.parametrize("engine", engines(kernel_only=True))
+def test_per_dispatch_pack_path_counts_a_plane_per_dispatch(adj, batch, vt,
+                                                            labels, engine):
+    """The per-dispatch pack path evaluates the plane inside each
+    dispatch, reused filter or not."""
+    cond, keep = CONDS[1]
+    filt = LabelFilter(vt, cond)
+    for _ in range(2):
+        ids, moved = _filter_counts(adj, batch, engine, filt, resident=False)
+        assert moved == {"filter.requests": 1, "filter.planes": 1}
+        np.testing.assert_array_equal(
+            ids, _filtered_ids(adj, batch, labels, keep))
 
 
 @pytest.mark.parametrize("engine", engines(kernel_only=True))
