@@ -7,12 +7,12 @@ unrolled into straight-line word ops at trace time -- no recursion, no
 interpretive dispatch on device.
 
 * ``cond_bitmap_pallas`` -- evaluate the program over the label columns'
-  interval position lists: each bit position finds its run per leaf by a
-  binary search (O(log |P|) per lane, lane-parallel; XLA work), then a
-  kernel combines the leaf bit planes by the unrolled program a word tile
-  at a time and packs bits to uint32 words.
-  The O(|P|) storage advantage of the RLE interval lists is preserved; the
-  dense per-vertex boolean column is never materialized.
+  interval position lists in one Mosaic kernel, a word tile at a time:
+  each leaf enters a tile with the parity of the run boundaries before
+  it and flips the bits at or past each boundary inside it, in packed
+  words; the program then combines the leaves' words.  A plane costs
+  O(n_words + runs) -- no lane searches for its run, and the dense
+  per-vertex boolean column is never materialized.
 
 * ``fused_decode_filter_bitmap_batch`` -- the filtering plane fused with
   the batched retrieval plane: miss-page delta decode (+ host-fed cached
@@ -33,96 +33,132 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.labels import eval_program
-from repro.kernels._pad import note_trace, pallas_interpret
+from repro.kernels._pad import (next_multiple, note_trace,
+                                pallas_interpret)
 from repro.kernels.pac_decode.kernel import (_bitmap_tail_pallas,
                                              _page_deltas, _scan_rows_pallas,
                                              fused_gather_bitmap)
 
 WORD_TILE = 64    # callers pad the predicate plane to this many words
-COND_TILE = 512   # output words per grid step of the cond kernel
+COND_ROWS = 32    # the cond kernel's output block: (COND_ROWS, 128) words
+COND_TILE = COND_ROWS * 128   # output words per grid step
+RUN_CHUNK = 1024  # run positions of one leaf held in SMEM at a time
 
 
-def _leaf_planes(pos, meta, lanes):
-    """Each label's bit plane at ``lanes``: the run holding a lane is
-    found by binary search (O(log |P|) per lane, lane-parallel), and its
-    parity against the first run's value gives the bit."""
-    leaves = []
-    for i in range(pos.shape[0]):
-        run = jnp.searchsorted(pos[i], lanes,
-                               side="right").astype(jnp.int32) - 1
-        leaves.append((meta[i, 0] ^ (run & 1)).astype(jnp.int32) == 1)
-    return leaves
+def _toggle_words(acc, widx, rel):
+    """XOR-toggle every bit at or past tile-relative bit ``rel``: whole
+    words past its word, the high bits of its own word."""
+    w, b = rel >> 5, rel & 31
+    return acc ^ jnp.where(widx > w, -1,
+                           jnp.where(widx == w, jnp.left_shift(-1, b), 0))
 
 
-def eval_cond_bits(pos, meta, lanes, ops: Tuple[Tuple, ...]):
-    """Compiled program over leaf bit planes, statically unrolled.
+def _cond_kernel(meta_ref, pos_hbm, out_ref, buf, cursor, sem, *, ops,
+                 n_pos):
+    """One word tile of the predicate plane, from the run boundaries
+    inside it.
 
-    ``pos`` int32[k, n_pos] -- each label's interval position list, padded
-    with ``count`` so out-of-range lanes land in the last run; ``meta``
-    int32[k, 2] = (first_value, count); ``lanes`` int32[t] -- absolute bit
-    positions.  Each label's plane is looked up once
-    (:func:`_leaf_planes`); the op stream then runs through the one
-    shared stack machine (:func:`repro.core.labels.eval_program`) over
-    traced jnp planes.  Returns bool[t]; lanes >= count are forced False
-    so NOT never sets bits past the row count.
+    Grid steps walk the tiles in order; each leaf keeps in SMEM a cursor
+    into its run positions (the number already passed, whose parity
+    gives the leaf's bit at the tile's first lane) and the
+    ``RUN_CHUNK`` positions around it, DMAed in as the cursor crosses
+    into them, so run lists of any length fit.  A position ``p`` in the
+    tile flips every bit at or past ``p`` -- the parity of ``#{pos <=
+    lane}``, which is what ``searchsorted(side="right") - 1`` gives.
+    The leaves are packed words from the start, so the unrolled program
+    combines whole words and no per-bit plane exists.  The next
+    position rides in the loop's carry: its condition reads no ref,
+    which the interpreter would not see change.
     """
-    return eval_program(ops, _leaf_planes(pos, meta, lanes)) \
-        & (lanes < meta[0, 1])
+    t = pl.program_id(0)
+    k = meta_ref.shape[0] // 2
+    count = meta_ref[1]
+    span = out_ref.shape[0] * out_ref.shape[1] * 32
+    base = t * span
+    lim = jnp.minimum(base + span, count)
+    widx = (jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
+            * out_ref.shape[1]
+            + jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1))
 
+    def load(i, j):
+        """Bring leaf ``i``'s chunk holding position ``j`` into SMEM."""
+        copy = pltpu.make_async_copy(
+            pos_hbm.at[pl.ds(pl.multiple_of(i * n_pos + j, RUN_CHUNK),
+                           RUN_CHUNK)],
+            buf.at[pl.ds(i * RUN_CHUNK, RUN_CHUNK)], sem)
+        copy.start()
+        copy.wait()
 
-def pack_bits(bits):
-    """bool[n_words * 32] -> uint32[n_words] (sum of distinct powers == OR)."""
-    b = bits.reshape(-1, 32).astype(jnp.uint32)
-    shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
-    return (b << shifts).sum(axis=1, dtype=jnp.uint32)
+    @pl.when(t == 0)
+    def _init():
+        for i in range(k):
+            cursor[i] = 0
+            load(i, 0)
 
+    leaves = []
+    for i in range(k):
+        def run_pos(j, i=i):
+            return buf[i * RUN_CHUNK + j % RUN_CHUNK]
 
-def _cond_kernel(count_ref, leaf_ref, out_ref, *, ops):
-    """One word tile: leaf planes laid out ``[bit, word]`` -> program ->
-    words, packed by a sublane sum of distinct powers of two (== OR)."""
-    words = out_ref.shape[1]
-    bit = jax.lax.broadcasted_iota(jnp.int32, (32, words), 0)
-    word = pl.program_id(0) * words \
-        + jax.lax.broadcasted_iota(jnp.int32, (32, words), 1)
-    leaves = [leaf_ref[i] == 1 for i in range(leaf_ref.shape[0])]
-    bits = eval_program(ops, leaves) & (word * 32 + bit < count_ref[0])
-    out_ref[...] = jnp.sum(jnp.where(bits, jnp.left_shift(1, bit), 0),
-                           axis=0, keepdims=True)
+        def step(carry, i=i):
+            j, p, acc = carry
+            acc = _toggle_words(acc, widx, p - base)
+
+            @pl.when((j + 1) % RUN_CHUNK == 0)
+            def _next():
+                load(i, j + 1)
+            return j + 1, run_pos(j + 1), acc
+
+        j0 = cursor[i]
+        fill = -(meta_ref[2 * i] ^ 1 ^ (j0 & 1))
+        j1, _, acc = jax.lax.while_loop(
+            lambda c: c[1] < lim, step,
+            (j0, run_pos(j0), jnp.full(out_ref.shape, fill, jnp.int32)))
+        cursor[i] = j1
+        leaves.append(acc)
+    rem = jnp.clip(count - (t * (span // 32) + widx) * 32, 0, 32)
+    valid = jnp.where(rem == 32, -1, jnp.left_shift(1, rem & 31) - 1)
+    out_ref[...] = eval_program(ops, leaves) & valid
 
 
 def _cond_words(pos, meta, n_words: int, ops: Tuple[Tuple, ...],
                 interpret: Optional[bool]):
     """Predicate plane -> ``uint32[n_words]`` (see cond_bitmap_pallas)."""
-    k = pos.shape[0]
-    lanes = (jnp.arange(n_words, dtype=jnp.int32)[None, :] * 32
-             + jnp.arange(32, dtype=jnp.int32)[:, None])
-    leaves = jnp.stack(_leaf_planes(pos, meta, lanes)).astype(jnp.int32)
-    words = min(COND_TILE, n_words)
+    k, n_pos = pos.shape
+    n_pad = next_multiple(n_pos + 1, RUN_CHUNK)   # >= 1 sentinel a leaf
+    flat = jnp.pad(pos, ((0, 0), (0, n_pad - n_pos)),
+                   constant_values=meta[0, 1]).reshape(-1)
+    n_tiles = -(-n_words // COND_TILE)
     out = pl.pallas_call(
-        functools.partial(_cond_kernel, ops=ops),
+        functools.partial(_cond_kernel, ops=ops, n_pos=n_pad),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(-(-n_words // words),),
-            in_specs=[pl.BlockSpec((k, 32, words),
-                                   lambda w, count: (0, 0, w))],
-            out_specs=pl.BlockSpec((1, words), lambda w, count: (0, w))),
-        out_shape=jax.ShapeDtypeStruct((1, n_words), jnp.int32),
+            grid=(n_tiles,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((COND_ROWS, 128), lambda t, meta: (t, 0)),
+            scratch_shapes=[pltpu.SMEM((k * RUN_CHUNK,), jnp.int32),
+                            pltpu.SMEM((k,), jnp.int32),
+                            pltpu.SemaphoreType.DMA]),
+        out_shape=jax.ShapeDtypeStruct((n_tiles * COND_ROWS, 128),
+                                       jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=pallas_interpret(interpret),
-    )(meta[0, 1:], leaves)
-    return jax.lax.bitcast_convert_type(out.reshape(n_words), jnp.uint32)
+    )(meta.reshape(-1), flat)
+    return jax.lax.bitcast_convert_type(out.reshape(-1)[:n_words],
+                                        jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("n_words", "ops", "interpret"))
 def cond_bitmap_pallas(pos, meta, n_words: int, ops: Tuple[Tuple, ...],
                        interpret: Optional[bool] = None):
-    """pos int32[k, n_pos] (padded with count), meta int32[k, 2] =
-    (first_value, count), ``ops`` the static postfix program.  Returns
+    """pos int32[k, n_pos] (sorted, padded with count), meta int32[k, 2]
+    = (first_value, count), ``ops`` the static postfix program.  Returns
     uint32[n_words].
 
-    The per-lane binary searches run in XLA (Mosaic lowers no
-    searchsorted) into ``[k, 32, n_words]`` leaf planes, bit-major so
-    that the kernel packs each word along sublanes; the kernel evaluates
-    the program a word tile at a time.
+    One Mosaic kernel: each word tile reads only the run boundaries that
+    fall inside it (:func:`_cond_kernel`), so a plane costs O(n_words +
+    runs); bits at or past ``count`` are zero.
     """
     note_trace("cond_bitmap_pallas")
     return _cond_words(pos, meta, n_words, ops, interpret)
@@ -182,9 +218,9 @@ def fused_gather_decode_filter_bitmap_batch(first, pos, mind, packed, staged,
     label plane equally resident: ``fwords`` is the predicate's
     **device-cached bitmap plane** (``FilterPlan.device_bitmap`` -- built
     once per (engine, n_words) from the RLE interval lists, label columns
-    are immutable), so the dispatch ships no label bytes and re-evaluates
-    no per-lane binary searches -- the bitmap tail ANDs each word tile of
-    the resident plane into the neighbor words.  ``words_init`` is
+    are immutable), so the dispatch ships no label bytes and rebuilds no
+    plane -- the bitmap tail ANDs each word tile of the resident plane
+    into the neighbor words.  ``words_init`` is
     accepted for signature parity and not read.  Returns ``(words,
     ids)`` (``ids`` in ``idx`` order), or ``words`` alone without
     ``want_ids``.

@@ -43,7 +43,7 @@ class FilterPlan:
     """Padded kernel inputs for one (vertex table, program) pair.
 
     ``pos`` stacks every leaf label's interval position list, padded with
-    ``count`` (the searchsorted sentinel); ``meta[i] = (first_value,
+    ``count`` (the sentinel past every run); ``meta[i] = (first_value,
     count)``.  Built once per filter and reused across dispatches (the
     arrays are a few KB -- the whole point of the RLE interval lists).
 
@@ -52,8 +52,8 @@ class FilterPlan:
     arrays on device once per engine (filter dispatches ship no label
     bytes), and :meth:`device_bitmap` caches the fully evaluated
     predicate bitmap plane on device per (engine, n_words) -- the
-    resident fused retrieval path ANDs that plane instead of re-running
-    the per-lane run binary searches every dispatch.
+    resident fused retrieval path ANDs that plane instead of rebuilding
+    it every dispatch.
     """
 
     program: CondProgram
@@ -79,6 +79,14 @@ class FilterPlan:
     @property
     def n_words(self) -> int:
         return -(-self.count // 32)
+
+    def count_plane(self) -> None:
+        """Count one plane evaluated on the device (``filter.planes``)
+        and the run positions it walks, padding left out
+        (``filter.plane_runs``)."""
+        obs.count("filter.planes")
+        obs.count("filter.plane_runs",
+                  np.count_nonzero(self.pos < self.count))
 
     def qual_range(self) -> Tuple[int, int]:
         """Half-open hull ``[lo, hi)`` of the qualifying ids.
@@ -114,12 +122,13 @@ class FilterPlan:
         device-mirrored run arrays (tile-padded, then sliced); lanes past
         ``count`` are zero, matching the per-dispatch evaluation of the
         non-resident fused kernel bit for bit.  Each evaluation counts
-        one ``filter.planes``.
+        one ``filter.planes`` and the plan's ``filter.plane_runs``
+        (:meth:`count_plane`).
         """
         key = (engine, n_words)
         words = self._device_bitmaps.get(key)
         if words is None:
-            obs.count("filter.planes")
+            self.count_plane()
             pos, meta = self.device(engine)
             padded = next_multiple(max(n_words, 1), K.WORD_TILE)
             fn = K.cond_bitmap_pallas if engine == "pallas" \
@@ -177,7 +186,7 @@ def label_filter_bitmap(vt: VertexTable, cond: Union[Cond, CondProgram],
         raise ValueError(f"unknown engine {engine!r}; want one of {ENGINES}")
     plan = make_plan(vt, program)
     n_words = next_multiple(plan.n_words or 1, K.WORD_TILE)
-    obs.count("filter.planes")
+    plan.count_plane()
     if engine == "pallas":
         words = K.cond_bitmap_pallas(jnp.asarray(plan.pos),
                                      jnp.asarray(plan.meta),

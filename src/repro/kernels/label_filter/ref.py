@@ -1,4 +1,8 @@
-"""Pure-jnp oracle for label_filter (same padded inputs, same programs)."""
+"""Pure-jnp oracle for label_filter (same padded inputs, same programs).
+
+The predicate plane here finds each lane's run by binary search, independent
+of the kernel's walk over the run boundaries of each word tile.
+"""
 from __future__ import annotations
 
 import functools
@@ -7,12 +11,46 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.labels import eval_program
 from repro.kernels._pad import note_trace
 from repro.kernels.pac_decode.kernel import (_bitmap_scatter,
                                              _decode_plan_rows, _gather_rows)
 from repro.kernels.pac_decode.ref import decode_pages_ref
 
-from .kernel import eval_cond_bits, pack_bits
+
+def _leaf_planes(pos, meta, lanes):
+    """Each label's bit plane at ``lanes``: the run holding a lane is
+    found by binary search (O(log |P|) per lane, lane-parallel), and its
+    parity against the first run's value gives the bit."""
+    leaves = []
+    for i in range(pos.shape[0]):
+        run = jnp.searchsorted(pos[i], lanes,
+                               side="right").astype(jnp.int32) - 1
+        leaves.append((meta[i, 0] ^ (run & 1)).astype(jnp.int32) == 1)
+    return leaves
+
+
+def eval_cond_bits(pos, meta, lanes, ops: Tuple[Tuple, ...]):
+    """Compiled program over leaf bit planes, statically unrolled.
+
+    ``pos`` int32[k, n_pos] -- each label's interval position list, padded
+    with ``count`` so out-of-range lanes land in the last run; ``meta``
+    int32[k, 2] = (first_value, count); ``lanes`` int32[t] -- absolute bit
+    positions.  Each label's plane is looked up once
+    (:func:`_leaf_planes`); the op stream then runs through the one
+    shared stack machine (:func:`repro.core.labels.eval_program`) over
+    traced jnp planes.  Returns bool[t]; lanes >= count are forced False
+    so NOT never sets bits past the row count.
+    """
+    return eval_program(ops, _leaf_planes(pos, meta, lanes)) \
+        & (lanes < meta[0, 1])
+
+
+def pack_bits(bits):
+    """bool[n_words * 32] -> uint32[n_words] (sum of distinct powers == OR)."""
+    b = bits.reshape(-1, 32).astype(jnp.uint32)
+    shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
+    return (b << shifts).sum(axis=1, dtype=jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("n_words", "ops"))

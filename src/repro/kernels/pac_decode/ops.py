@@ -909,7 +909,7 @@ def _retrieve_pac_batch_fused(col: DeltaColumn, los, his,
             from repro.kernels.label_filter import ref as LR
             fn = (LK.fused_decode_filter_bitmap_batch if engine == "pallas"
                   else LR.fused_filter_batch_ref)
-            obs.count("filter.planes")  # evaluated inside the dispatch
+            filter_plan.count_plane()  # evaluated inside the dispatch
             words, ids = fn(*jargs, *fargs, page_size=ps, n_words=n_words,
                             ops=filter_plan.program.ops)
     # the miss pages, padded to m_pad, are the only ones decoded: cache

@@ -14,8 +14,9 @@ session an annotation is inactive and costs well under a microsecond.
 lanes, pages decoded in the resident slab kernel
 (``decode.kernel_pages``), bytes moved across the host-device
 boundary, and the label plane's retrievals that carry a filter
-(``filter.requests``) and predicate planes evaluated on the device
-(``filter.planes``), counted where the work happens.  ``counters``
+(``filter.requests``), predicate planes evaluated on the device
+(``filter.planes``) and the run positions those planes walk
+(``filter.plane_runs``), counted where the work happens.  ``counters``
 returns a copy; a reader takes the change between two copies.  The
 kernel layer's trace counter (:func:`repro.kernels._pad.note_trace`)
 records here too, under ``traces/<entry>``.

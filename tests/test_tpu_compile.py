@@ -123,6 +123,10 @@ COMPILES = {
             n_words=N_WORDS, ops=_OPS, interpret=False),
     "cond_bitmap_pallas": lambda s: LK.cond_bitmap_pallas.lower(
         *_label_plan(s), n_words=N_WORDS, ops=_OPS, interpret=False),
+    # a run list far past SMEM: the kernel streams it in chunks
+    "cond_bitmap_pallas[runs=2**21]": lambda s: LK.cond_bitmap_pallas.lower(
+        *_label_plan(s, k=1, n_pos=2**21), n_words=N_WORDS,
+        ops=compile_cond(L("a")).ops, interpret=False),
     "flash_attention": lambda s: FK.flash_attention.lower(
         s((15, 512, 64), jnp.bfloat16), s((15, 512, 64), jnp.bfloat16),
         s((15, 512, 64), jnp.bfloat16), interpret=False),
@@ -136,6 +140,26 @@ def test_entry_compiles_for_v5e(entry, one_chip, no_compile_cache):
 
     compiled = COMPILES[entry](sds).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_cond_plane_has_no_per_lane_work_outside_its_kernel(
+        one_chip, no_compile_cache):
+    """The predicate plane is one Mosaic kernel over word tiles: no
+    array in its program has an element per lane (the ``[k, 32,
+    n_words]`` leaf planes of a per-lane run search), and no loop runs
+    outside the kernel."""
+    import math
+    import re
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = COMPILES["cond_bitmap_pallas"](sds).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " while(" not in text
+    sizes = [math.prod(int(d) for d in dims.split(",") if d)
+             for dims in re.findall(r"[suf]\d+\[([\d,]*)\]", text)]
+    assert sizes and max(sizes) < 32 * N_WORDS
 
 
 def test_sharded_fused_entry_compiles_for_v5e_2x2(topo, no_compile_cache,
